@@ -101,7 +101,10 @@ class RunConfig:
     remat: str = "full"             # none | full | dots
     scan_unroll: int = 1
     # attention execution
-    kernel_mode: str = "reference"  # reference | pallas | pallas_interpret
+    # None picks the platform's path (Pallas on TPU, the jnp reference
+    # elsewhere: kernels.ops.resolve_mode); reference | pallas_interpret
+    # name one explicitly (the training forward, kernel tests)
+    kernel_mode: str | None = None
     attn_block_q: int = 512
     attn_block_kv: int = 1024
     naive_attn_below: int = 2049    # use naive path for short seqs

@@ -15,12 +15,6 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-try:
-    _CompilerParams = pltpu.CompilerParams
-except AttributeError:
-    _CompilerParams = pltpu.TPUCompilerParams
-
-
 def _gmm_kernel(lhs_ref, rhs_ref, out_ref, acc_ref, *, nk):
     ik = pl.program_id(3)
 
@@ -74,7 +68,7 @@ def grouped_matmul(lhs, rhs, *, block_m=128, block_k=512, block_n=512,
         out_shape=jax.ShapeDtypeStruct((G, lp.shape[1], rp.shape[2]),
                                        lhs.dtype),
         scratch_shapes=[pltpu.VMEM((block_m, block_n), jnp.float32)],
-        compiler_params=_CompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,

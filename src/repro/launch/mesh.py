@@ -11,6 +11,7 @@ from __future__ import annotations
 import math
 
 import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
@@ -24,10 +25,18 @@ def make_production_mesh(*, multi_pod: bool = False):
             f"mesh {shape} needs {n} devices but only {len(devices)} "
             f"present — run through launch/dryrun.py, which forces 512 "
             f"host platform devices")
-    return jax.make_mesh(shape, axes, devices=devices)
+    return _auto_mesh(shape, axes, devices)
 
 
 def make_mesh_for(shape: tuple[int, ...], axes: tuple[str, ...]):
     """Arbitrary mesh (elastic re-planning, tests on small device counts)."""
     n = math.prod(shape)
-    return jax.make_mesh(shape, axes, devices=jax.devices()[:n])
+    return _auto_mesh(shape, axes, jax.devices()[:n])
+
+
+def _auto_mesh(shape, axes, devices):
+    # jax.make_mesh defaults to Explicit axes, under which the logical
+    # sharding constraints (sharding/rules.py) and gathers raise; every
+    # sharding in this repo is a GSPMD hint, so the axes are Auto
+    return jax.make_mesh(shape, axes, devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -1,7 +1,10 @@
 """Serving launcher: batched generation with per-phase power capping.
 
-  PYTHONPATH=src python -m repro.launch.serve --arch llama3.2-3b --reduced \
+  PYTHONPATH=src python -m repro.launch.serve --arch minitron-4b --reduced \
       --requests 8 --new 16
+
+Without ``--reduced`` the model is built at its published widths, with
+weights drawn directly in the compute dtype (bf16), as on the chip.
 
 The engine runs prefill and decode under distinct phase caps from a
 ``repro.power.PowerManager`` (compute-bound prefill stays near max;
@@ -18,6 +21,7 @@ import jax
 
 from repro.configs.base import reduced as reduce_cfg
 from repro.configs.registry import ARCH_IDS, get_model_config, get_run_config
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import lm
 from repro.models.layers import Ctx
 from repro.models.params import init_params
@@ -29,7 +33,8 @@ from repro.sharding import RULE_SETS
 def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="llama3.2-3b", choices=ARCH_IDS)
-    ap.add_argument("--reduced", action="store_true", default=True)
+    ap.add_argument("--reduced", action="store_true",
+                    help="same-family toy widths (CPU-sized)")
     ap.add_argument("--requests", type=int, default=8)
     ap.add_argument("--new", type=int, default=16)
     ap.add_argument("--batch-size", type=int, default=4)
@@ -43,14 +48,18 @@ def main() -> None:
                     choices=available_metrics())
     args = ap.parse_args()
 
+    enable_compile_cache()
     cfg = get_model_config(args.arch)
     if args.reduced:
         cfg = reduce_cfg(cfg)
     if cfg.family == "audio":
         raise SystemExit("encoder-only arch has no decode path")
-    run = get_run_config(args.arch, remat="none", logits_chunk=64)
+    # serving keeps no f32 masters: weights are drawn in the compute dtype
+    run = get_run_config(args.arch, remat="none", logits_chunk=64,
+                         param_dtype="bfloat16")
     ctx = Ctx(run, RULE_SETS[run.serve_rules_name], None)
-    params = init_params(lm.model_decls(cfg), jax.random.PRNGKey(0))
+    params = init_params(lm.model_decls(cfg), jax.random.PRNGKey(0),
+                         run.param_dtype)
 
     # phase caps for the FULL arch at production serving scale; the engine
     # below drives the same phases on the reduced model
@@ -77,8 +86,9 @@ def main() -> None:
         print(f"req {r.uid}: {len(r.generated)} tokens -> "
               f"{r.generated[:8]}{'...' if len(r.generated) > 8 else ''}")
     n_tok = sum(len(r.generated) for r in done)
-    print(f"[throughput] {n_tok} tokens in {wall:.2f}s "
-          f"({n_tok / wall:.1f} tok/s, {engine.sync_count} host syncs)")
+    print(f"[run] {n_tok} tokens, {engine.sync_count} host syncs, "
+          f"{wall:.2f}s host wall clock including compilation "
+          f"({jax.devices()[0].platform})")
     e = pm.account_step()
     dt, de = pm.overhead_totals()
     print(f"[energy] modeled step {e['energy_j']:.1f}J "

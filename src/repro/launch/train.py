@@ -24,6 +24,7 @@ from repro.configs.base import reduced as reduce_cfg
 from repro.configs.registry import ARCH_IDS, get_model_config, get_run_config
 from repro.data.pipeline import DataConfig, TokenSource
 from repro.hw.tpu import DEFAULT_SUPERCHIP
+from repro.launch.compile_cache import enable_compile_cache
 from repro.launch.mesh import make_mesh_for
 from repro.models.layers import Ctx
 from repro.power import PodPowerArbiter, PowerManager, available_metrics
@@ -59,6 +60,7 @@ def main() -> None:
     args = ap.parse_args()
 
     maybe_init_distributed()
+    enable_compile_cache()
     cfg = get_model_config(args.arch)
     if args.reduced:
         cfg = reduce_cfg(cfg)
@@ -115,7 +117,8 @@ def main() -> None:
             state, start = checkpoint.restore(args.ckpt_dir, state)
             state = jax.tree.map(jnp.asarray, state)
             print(f"[restore] step {start} (restart #{restart})")
-        step_fn = jax.jit(make_train_step(cfg, run, ctx))
+        # the loop rebinds ``state`` every step: donate it (held once)
+        step_fn = jax.jit(make_train_step(cfg, run, ctx), donate_argnums=(0,))
         watchdog = StragglerWatchdog()
         with PreemptionGuard() as guard:
             for i in range(start, args.steps):

@@ -18,11 +18,6 @@ from repro.kernels import ops
 from repro.models.params import PD
 from repro.sharding.rules import LogicalRules, with_constraint
 
-try:                                   # jax >= 0.6 exports it at top level
-    _shard_map = jax.shard_map
-except AttributeError:                 # 0.4.x keeps it in experimental
-    from jax.experimental.shard_map import shard_map as _shard_map
-
 
 @dataclasses.dataclass(frozen=True)
 class Ctx:
@@ -159,11 +154,13 @@ def attention_decls(cfg: ModelConfig, layers: int = 0,
 
 def apply_attention(ctx: Ctx, cfg: ModelConfig, p: dict, x, cos, sin, *,
                     local_window=None, cache=None, cache_index=None,
-                    x_kv=None, block_tables=None):
+                    x_kv=None, block_tables=None, layer=None):
     """x: (B, S, d_in).  With ``cache`` (dict k/v (B, Smax, K, hd)) performs a
     decode step and returns (y, new_cache).  With ``block_tables``
     ((B, max_blocks) int32) the cache leaves are PAGED pools
-    (n_blocks, bs, K, hd) and every read/write goes through the table."""
+    (n_blocks, bs, K, hd) and every read/write goes through the table;
+    with ``layer`` too they are the whole layer stack
+    (L, n_blocks, bs, K, hd), read and updated in place at that layer."""
     c = ctx.cdtype
     x_kv = x if x_kv is None else x_kv
     B, S = x.shape[:2]
@@ -190,13 +187,13 @@ def apply_attention(ctx: Ctx, cfg: ModelConfig, p: dict, x, cos, sin, *,
         idx_vec = (jnp.asarray(cache_index, jnp.int32) if per_slot
                    else jnp.full((B,), cache_index, jnp.int32))
         ck, cv = ops.kv_cache_update_paged(cache["k"], cache["v"], k, v,
-                                           idx_vec, block_tables,
+                                           idx_vec, block_tables, layer,
                                            mode=ctx.run.kernel_mode)
         new_cache = {"k": ck, "v": cv}
         kv_len = idx_vec + x.shape[1]
-        out = ops.decode_attention_paged(q, ck.astype(c), cv.astype(c),
-                                         kv_len, block_tables,
-                                         softcap=cfg.attn_softcap,
+        # pools are built in the compute dtype (lm.init_paged_cache)
+        out = ops.decode_attention_paged(q, ck, cv, kv_len, block_tables,
+                                         layer, softcap=cfg.attn_softcap,
                                          local_window=local_window,
                                          scale=scale,
                                          mode=ctx.run.kernel_mode)
@@ -375,7 +372,7 @@ def _decode_attention_seqsharded(ctx: Ctx, cfg: ModelConfig, q, cache,
         o = o / jnp.maximum(l, 1e-30)[..., None]
         return o.reshape(B_l, 1, H, hd).astype(c), ck, cv
 
-    out, ck, cv = _shard_map(
+    out, ck, cv = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(rep_spec, cache_spec, cache_spec, rep_spec, rep_spec, P()),
         out_specs=(rep_spec, cache_spec, cache_spec),
@@ -626,7 +623,7 @@ def _moe_shard_map(ctx: Ctx, cfg: ModelConfig, p: dict, x, top_p, top_e):
                                  1, B_l * S_l)
         return y_l.reshape(B_l, S_l, -1)
 
-    y = _shard_map(
+    y = jax.shard_map(
         local_fn, mesh=mesh,
         in_specs=(tok_spec, tok_spec, tok_spec, w_spec, w_spec, w_spec),
         out_specs=tok_spec,

@@ -154,13 +154,14 @@ def _stack_scan(ctx: L.Ctx, body, carry, xs):
 
 def _tf_block(ctx: L.Ctx, cfg: ModelConfig, p, h, cos, sin, *,
               local_window=None, cache=None, cache_index=None,
-              block_tables=None):
+              block_tables=None, layer=None):
     """One transformer block; returns (h, new_cache, aux)."""
     post = "post_ln1" in p
     a_in = L.apply_norm(cfg, p["ln1"], h)
     attn_out, new_cache = L.apply_attention(
         ctx, cfg, p["attn"], a_in, cos, sin, local_window=local_window,
-        cache=cache, cache_index=cache_index, block_tables=block_tables)
+        cache=cache, cache_index=cache_index, block_tables=block_tables,
+        layer=layer)
     if post:
         attn_out = L.apply_norm(cfg, p["post_ln1"], attn_out)
     # NOTE: do NOT pin the residual adds with sharding constraints — it
@@ -185,6 +186,25 @@ def _scan_tf_layers(ctx: L.Ctx, cfg: ModelConfig, stack, h, cos, sin, *,
     """Scan one homogeneous transformer stack.  cache: stacked kv or None.
     ``block_tables`` rides as a closure capture — it is layer-invariant, so
     it must not be scanned over with the per-layer cache leaves."""
+    if block_tables is not None:
+        # paged pools ride in the scan CARRY, updated in place at a traced
+        # layer index: scanned as xs -> ys they would be held twice, the
+        # input stack and the output stack (the pool is most of HBM)
+        def paged_body(carry, xs):
+            h, aux, pools = carry
+            p, layer = xs
+            h, pools, a = _tf_block(ctx, cfg, p, h, cos, sin,
+                                    local_window=local_window, cache=pools,
+                                    cache_index=cache_index,
+                                    block_tables=block_tables, layer=layer)
+            return (h, aux + a, pools), None
+
+        n = jax.tree.leaves(stack)[0].shape[0]
+        (h, aux, new_cache), _ = _stack_scan(
+            ctx, _remat(ctx, paged_body),
+            (h, jnp.zeros((), jnp.float32), cache),
+            (stack, jnp.arange(n, dtype=jnp.int32)))
+        return h, aux, new_cache
 
     def body(carry, xs):
         h, aux = carry
@@ -679,7 +699,7 @@ def int8_payload_ratio(cfg: ModelConfig, itemsize: int = 2) -> float:
 
 
 def export_slot(cfg: ModelConfig, cache, slot: int, kv_len: int,
-                mode: str = "reference", quantize: bool = False,
+                mode: str | None = None, quantize: bool = False,
                 row_start: int = 0) -> dict:
     """Lift slot ``slot``'s state out of a batched decode cache.
 
@@ -726,7 +746,7 @@ def export_slot(cfg: ModelConfig, cache, slot: int, kv_len: int,
 
 
 def import_slot(cfg: ModelConfig, cache, payload, slot: int,
-                mode: str = "reference", row_offset: int = 0):
+                mode: str | None = None, row_offset: int = 0):
     """Install an ``export_slot`` payload into slot ``slot`` of ``cache``.
 
     "rows" leaves are zero-padded to the destination's ``max_seq`` and
@@ -796,7 +816,7 @@ def _paged_row_coords(blocks, block_size: int, row_start: int, row_stop: int):
 
 def export_slot_paged(cfg: ModelConfig, cache, slot: int, blocks,
                       block_size: int, kv_len: int, *, row_start: int = 0,
-                      mode: str = "reference", quantize: bool = False):
+                      mode: str | None = None, quantize: bool = False):
     """``export_slot`` for a paged cache: rows-leaves are gathered out of
     the block pools through the slot's host-side block list, producing the
     SAME payload schema as the dense exporter — payloads are
@@ -828,7 +848,7 @@ def export_slot_paged(cfg: ModelConfig, cache, slot: int, blocks,
 
 def import_slot_paged(cfg: ModelConfig, cache, payload, slot: int, blocks,
                       block_size: int, *, row_offset: int = 0,
-                      mode: str = "reference"):
+                      mode: str | None = None):
     """Install an ``export_slot``/``export_slot_paged`` payload into a
     paged cache: rows scatter to the (block, offset) rows the slot's block
     list maps [row_offset, row_offset + rows) to.  Unlike the dense

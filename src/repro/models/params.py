@@ -14,6 +14,7 @@ Scan-stacked layers simply declare a leading "layers" dimension.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -49,25 +50,34 @@ def _fan_in(shape: tuple[int, ...]) -> int:
     return max(fan, 1)
 
 
-def init_params(decls, key: jax.Array):
-    """Materialize concrete parameters; every leaf gets a distinct key."""
+def init_params(decls, key: jax.Array, dtype=None):
+    """Materialize concrete parameters; every leaf gets a distinct key.
+
+    ``dtype`` (e.g. ``RunConfig.param_dtype``) overrides the declared
+    master dtype.  Each leaf is drawn directly in its dtype, one leaf at a
+    time, so a bf16 tree never holds an f32 copy of itself."""
     leaves, treedef = jax.tree.flatten(decls, is_leaf=_is_pd)
     keys = jax.random.split(key, max(len(leaves), 1))
 
     def make(pd: PD, k: jax.Array):
+        dt = jnp.dtype(dtype if dtype is not None else pd.dtype)
         if pd.init == "zeros":
-            return jnp.zeros(pd.shape, pd.dtype)
+            return jnp.zeros(pd.shape, dt)
         if pd.init == "ones":
-            return jnp.ones(pd.shape, pd.dtype)
+            return jnp.ones(pd.shape, dt)
         if pd.init == "embed":
             std = pd.scale if pd.scale is not None else 1.0
-            return (jax.random.normal(k, pd.shape, jnp.float32) * std
-                    ).astype(pd.dtype)
-        std = pd.scale if pd.scale is not None else _fan_in(pd.shape) ** -0.5
-        return (jax.random.normal(k, pd.shape, jnp.float32) * std
-                ).astype(pd.dtype)
+        else:
+            std = pd.scale if pd.scale is not None \
+                else _fan_in(pd.shape) ** -0.5
+        return _normal(k, pd.shape, dt, std)
 
     return treedef.unflatten([make(pd, k) for pd, k in zip(leaves, keys)])
+
+
+@functools.partial(jax.jit, static_argnums=(1, 2, 3))
+def _normal(key, shape, dtype, std):
+    return jax.random.normal(key, shape, dtype) * jnp.asarray(std, dtype)
 
 
 def abstract_params(decls):

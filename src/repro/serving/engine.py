@@ -407,14 +407,18 @@ class ServeEngine:
         self.trace_track = trace_track
         self._vt = 0.0
         # jit caches one program per (1, chunk_size) token shape — the
-        # chunk_plan power-of-two sizes bound the trace count
+        # chunk_plan power-of-two sizes bound the trace count.  Every step
+        # DONATES the cache (pools, block tables) and the decode-lane
+        # vectors it rewrites, so the device holds them once, not twice.
         mk = make_prefill_chunk_step_paged if paged else make_prefill_chunk_step
-        self._prefill_step = jax.jit(mk(cfg, run, ctx))
+        self._prefill_step = jax.jit(mk(cfg, run, ctx), donate_argnums=(1,))
         self._decode_fn = jax.jit(
-            make_decode_chunk_step(cfg, run, ctx, decode_chunk, max_seq))
-        self._admit_fn = jax.jit(_admit_step)
-        self._install_fn = jax.jit(_install_step)
-        self._reset_fn = jax.jit(_reset_mamba_slot)
+            make_decode_chunk_step(cfg, run, ctx, decode_chunk, max_seq),
+            donate_argnums=(1, 2, 3, 4, 5))
+        lanes = (0, 1, 2, 3)
+        self._admit_fn = jax.jit(_admit_step, donate_argnums=lanes)
+        self._install_fn = jax.jit(_install_step, donate_argnums=lanes)
+        self._reset_fn = jax.jit(_reset_mamba_slot, donate_argnums=(0,))
         if paged:
             rows_keys = [k for k, v in lm.cache_slot_spec(cfg).items()
                          if v == lm.SLOT_ROWS]
@@ -430,8 +434,8 @@ class ServeEngine:
                         lambda a: a.at[:, dst].set(a[:, src]), cache[key])
                 return out
 
-            self._table_fn = jax.jit(set_table_row)
-            self._copy_fn = jax.jit(copy_block)
+            self._table_fn = jax.jit(set_table_row, donate_argnums=(0,))
+            self._copy_fn = jax.jit(copy_block, donate_argnums=(0,))
         # warm snapshots awaiting a free slot (restored ahead of fresh
         # admissions — they carry finished work)
         self._restore_q: deque[SlotSnapshot] = deque()

@@ -48,7 +48,7 @@ def make_optimizer(run: RunConfig):
 def init_state(cfg: ModelConfig, run: RunConfig, key) -> TrainState:
     from repro.models.params import init_params
     decls = lm.model_decls(cfg)
-    params = init_params(decls, key)
+    params = init_params(decls, key, run.param_dtype)
     opt = make_optimizer(run)
     return TrainState(params=params, opt_state=opt.init(params),
                       step=jnp.zeros((), jnp.int32))
@@ -85,6 +85,10 @@ def state_logical_axes(cfg: ModelConfig, run: RunConfig | None = None) -> dict:
 
 
 def make_loss_fn(cfg: ModelConfig, run: RunConfig, ctx: Ctx):
+    # no kernel in repro.kernels defines a backward (no custom_vjp), so the
+    # differentiated forward runs the XLA attention and SSD path
+    ctx = dataclasses.replace(ctx, run=ctx.run.replace(kernel_mode="reference"))
+
     def loss_fn(params, batch):
         h, aux, _ = lm.forward(ctx, cfg, params, batch)
         labels = batch["labels"]

@@ -6,7 +6,8 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.kernels import ref
+from repro.configs.base import RunConfig
+from repro.kernels import ops, ref
 from repro.kernels.flash_attention import (cache_update, cache_update_paged,
                                            flash_attention, flash_decode,
                                            flash_decode_paged)
@@ -187,6 +188,51 @@ def test_flash_decode_paged_equals_dense_layout():
                          ref.paged_gather_ref(v_pool, tables), kv_len,
                          block_kv=bs, interpret=True)
     np.testing.assert_allclose(paged, dense, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("mode", ["pallas_interpret", "reference"])
+def test_paged_ops_read_and_write_one_layer_of_a_stack(mode):
+    """With ``layer`` the paged ops take the whole layer stack (the
+    engine's scan carries it) and touch exactly that layer."""
+    B, max_blocks, bs, H, K, D, L = 3, 4, 8, 4, 2, 32, 3
+    pools = [_paged_pools(16, bs, K, D, B, max_blocks, seed=s)
+             for s in range(L)]
+    k_stack = jnp.stack([p[0] for p in pools])
+    v_stack = jnp.stack([p[1] for p in pools])
+    tables = pools[0][2]
+    q = jax.random.normal(KEY, (B, 2, H, D))
+    kv_len = jnp.array([5, 17, 30], jnp.int32)
+    layer = jnp.asarray(1, jnp.int32)
+    out = ops.decode_attention_paged(q, k_stack, v_stack, kv_len, tables,
+                                     layer, mode=mode)
+    exp = ref.decode_attention_paged_ref(q, k_stack[1], v_stack[1], kv_len,
+                                         tables)
+    np.testing.assert_allclose(out, exp, atol=2e-5, rtol=2e-5)
+
+    kn = jax.random.normal(KEY, (B, 2, K, D))
+    idx = jnp.array([0, 15, 32], jnp.int32)          # last slot drops
+    got_k, got_v = ops.kv_cache_update_paged(k_stack, v_stack, kn, -kn, idx,
+                                             tables, layer, mode=mode)
+    exp_k, exp_v = ref.kv_cache_update_paged_ref(k_stack[1], v_stack[1], kn,
+                                                 -kn, idx, tables)
+    np.testing.assert_array_equal(got_k[1], exp_k)
+    np.testing.assert_array_equal(got_v[1], exp_v)
+    for other in (0, 2):
+        np.testing.assert_array_equal(got_k[other], k_stack[other])
+        np.testing.assert_array_equal(got_v[other], v_stack[other])
+
+
+def test_kernel_mode_follows_the_platform(monkeypatch):
+    """No user option picks the kernels: an unset mode resolves to the
+    jnp reference on CPU and to Pallas on TPU; a named mode (the training
+    forward's "reference", tests' "pallas_interpret") is kept."""
+    assert RunConfig().kernel_mode is None
+    assert jax.default_backend() == "cpu"
+    assert ops.resolve_mode(None) == "reference"
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    assert ops.resolve_mode(None) == "pallas"
+    for mode in ("reference", "pallas", "pallas_interpret"):
+        assert ops.resolve_mode(mode) == mode
 
 
 @pytest.mark.parametrize("idx", [

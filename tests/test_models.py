@@ -140,3 +140,22 @@ def test_causal_masking_is_causal():
     diff = jnp.abs(h1.astype(jnp.float32) - h2.astype(jnp.float32))
     assert float(diff[:, :-1].max()) < 1e-5     # prefix unchanged
     assert float(diff[:, -1].max()) > 1e-4      # last position changed
+
+
+def test_init_params_honours_param_dtype():
+    """Serving draws weights directly in the compute dtype; the default
+    keeps the declared f32 masters.  Both draw from the same per-leaf
+    distribution."""
+    decls = lm.model_decls(reduced(get_model_config("minitron-4b")))
+    f32 = init_params(decls, jax.random.PRNGKey(0))
+    bf16 = init_params(decls, jax.random.PRNGKey(0),
+                       get_run_config("minitron-4b",
+                                      param_dtype="bfloat16").param_dtype)
+    assert {a.dtype for a in jax.tree.leaves(f32)} == {jnp.dtype("float32")}
+    assert {a.dtype for a in jax.tree.leaves(bf16)} == {jnp.dtype("bfloat16")}
+    for a, b in zip(jax.tree.leaves(f32), jax.tree.leaves(bf16)):
+        assert a.shape == b.shape
+        b = b.astype(jnp.float32)
+        if a.size >= 4096:
+            assert abs(float(a.mean() - b.mean())) <= 0.1 * float(a.std())
+            assert abs(float(a.std() - b.std())) <= 0.1 * float(a.std())
