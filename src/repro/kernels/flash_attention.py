@@ -145,6 +145,7 @@ def flash_attention(q, k, v, *, causal=True, local_window=None, softcap=None,
                            lambda b, h, iq, ik, g=g: (b, h // g, ik, 0))
     out = pl.pallas_call(
         kernel,
+        name="flash_attention",
         grid=(B, H, nq, nkv),
         in_specs=[q_spec, kv_spec, kv_spec],
         out_specs=q_spec,
@@ -275,6 +276,7 @@ def flash_decode_paged(q, k_pool, v_pool, kv_len, block_tables, layer=None,
     )
     out = pl.pallas_call(
         kernel,
+        name="flash_decode_paged",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, rows, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
@@ -371,6 +373,7 @@ def cache_update_paged(k_pool, v_pool, k_new, v_new, index, block_tables,
                                s_logical=s_logical)
     k_out, v_out = pl.pallas_call(
         kernel,
+        name="cache_update_paged",
         grid_spec=grid_spec,
         out_shape=[
             jax.ShapeDtypeStruct(k_pool.shape, k_pool.dtype),

@@ -56,6 +56,7 @@ def grouped_matmul(lhs, rhs, *, block_m=128, block_k=512, block_n=512,
 
     out = pl.pallas_call(
         functools.partial(_gmm_kernel, nk=nk),
+        name="grouped_matmul",
         grid=(G, nm, nn, nk),
         in_specs=[
             pl.BlockSpec((1, block_m, block_k),

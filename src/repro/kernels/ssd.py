@@ -105,6 +105,7 @@ def ssd(x, dt, A, B, C, D=None, h0=None, *, chunk=128, interpret=False):
                               lambda b, h, c: (b, h, 0, 0))
     y, state = pl.pallas_call(
         kernel,
+        name="ssd",
         grid=(Bb, H, nchunks),
         in_specs=[
             pl.BlockSpec(memory_space=pltpu.SMEM),

@@ -8,6 +8,7 @@ config (dtypes, kernel mode), sharding rules and the mesh through the stack.
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Any
 
 import jax
@@ -31,6 +32,22 @@ class Ctx:
 
     def cst(self, x, *axes):
         return with_constraint(x, self.rules, self.mesh, *axes)
+
+
+def scoped(name: str):
+    """Decorate a model part so that every op it stages runs under
+    ``jax.named_scope(name)``: the compiled program's metadata, and so the
+    profiler's ``tf_op`` path of each device op, then name the part
+    (``embed``, ``norm``, ``attn_proj``, ``attn_core``, ``mlp``, ``ssm``,
+    ``logits``, ``sample``).  Compile-time metadata only: the ops and their
+    HLO names do not change."""
+    def wrap(fn):
+        @functools.wraps(fn)
+        def scoped_fn(*args, **kwargs):
+            with jax.named_scope(name):
+                return fn(*args, **kwargs)
+        return scoped_fn
+    return wrap
 
 
 def _stack(shape, layers):
@@ -64,6 +81,7 @@ def norm_decls(cfg: ModelConfig, layers: int = 0,
     raise ValueError(cfg.norm)
 
 
+@scoped("norm")
 def apply_norm(cfg: ModelConfig, p: dict, x, eps: float = 1e-6):
     xf = x.astype(jnp.float32)
     if cfg.norm == "rmsnorm":
@@ -92,6 +110,7 @@ def rmsnorm_gated(scale, y, z, eps: float = 1e-6):
 # rotary position embeddings (RoPE / partial-rotary / M-RoPE)
 # ===========================================================================
 
+@scoped("attn_proj")
 def rope_cos_sin(cfg: ModelConfig, positions):
     """positions: (B, S) int for RoPE, or (3, B, S) for M-RoPE.
     Returns cos/sin of shape (B, S, rot_half)."""
@@ -165,19 +184,42 @@ def apply_attention(ctx: Ctx, cfg: ModelConfig, p: dict, x, cos, sin, *,
     x_kv = x if x_kv is None else x_kv
     B, S = x.shape[:2]
     H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q = jnp.einsum("bsd,de->bse", x, p["wq"].astype(c)).reshape(B, S, H, hd)
-    k = jnp.einsum("bsd,de->bse", x_kv, p["wk"].astype(c)).reshape(B, S, K, hd)
-    v = jnp.einsum("bsd,de->bse", x_kv, p["wv"].astype(c)).reshape(B, S, K, hd)
-    q = ctx.cst(q, "act_batch", "act_seq", "act_heads", None)
-    k = ctx.cst(k, "act_batch", "act_seq", "act_kv_heads", None)
-    v = ctx.cst(v, "act_batch", "act_seq", "act_kv_heads", None)
-    if cfg.use_rope:
-        q = apply_rope(q, cos, sin, cfg.rotary_pct)
-        k = apply_rope(k, cos, sin, cfg.rotary_pct)
+    with jax.named_scope("attn_proj"):
+        q = jnp.einsum("bsd,de->bse", x, p["wq"].astype(c)
+                       ).reshape(B, S, H, hd)
+        k = jnp.einsum("bsd,de->bse", x_kv, p["wk"].astype(c)
+                       ).reshape(B, S, K, hd)
+        v = jnp.einsum("bsd,de->bse", x_kv, p["wv"].astype(c)
+                       ).reshape(B, S, K, hd)
+        q = ctx.cst(q, "act_batch", "act_seq", "act_heads", None)
+        k = ctx.cst(k, "act_batch", "act_seq", "act_kv_heads", None)
+        v = ctx.cst(v, "act_batch", "act_seq", "act_kv_heads", None)
+        if cfg.use_rope:
+            q = apply_rope(q, cos, sin, cfg.rotary_pct)
+            k = apply_rope(k, cos, sin, cfg.rotary_pct)
     scale = (cfg.query_scale ** -0.5 if cfg.query_scale is not None
              else cfg.head_dim ** -0.5)
+    out, new_cache = _attend(ctx, cfg, q, k, v, cache, cache_index,
+                             block_tables, layer, local_window, scale)
+    return attn_out_proj(ctx, p["wo"], out), new_cache
 
-    new_cache = None
+
+@scoped("attn_proj")
+def attn_out_proj(ctx: Ctx, wo, out):
+    """The output projection of attention heads out (B, S, H, hd)."""
+    B, S, H, hd = out.shape
+    y = jnp.einsum("bse,ed->bsd", out.reshape(B, S, H * hd),
+                   wo.astype(ctx.cdtype))
+    return ctx.cst(y, "act_batch", "act_seq", "act_embed")
+
+
+@scoped("attn_core")
+def _attend(ctx: Ctx, cfg: ModelConfig, q, k, v, cache, cache_index,
+            block_tables, layer, local_window, scale):
+    """The cache write and the attention kernel of ``apply_attention``;
+    returns (out (B, S, H, hd), the new cache or None)."""
+    c = ctx.cdtype
+    B = q.shape[0]
     if cache is not None and block_tables is not None:
         # paged path: per-slot offsets (or a scalar prefill cursor broadcast
         # to all slots) resolve to (block, offset) pool rows via the table.
@@ -189,8 +231,7 @@ def apply_attention(ctx: Ctx, cfg: ModelConfig, p: dict, x, cos, sin, *,
         ck, cv = ops.kv_cache_update_paged(cache["k"], cache["v"], k, v,
                                            idx_vec, block_tables, layer,
                                            mode=ctx.run.kernel_mode)
-        new_cache = {"k": ck, "v": cv}
-        kv_len = idx_vec + x.shape[1]
+        kv_len = idx_vec + q.shape[1]
         # pools are built in the compute dtype (lm.init_paged_cache)
         out = ops.decode_attention_paged(q, ck, cv, kv_len, block_tables,
                                          layer, softcap=cfg.attn_softcap,
@@ -198,19 +239,14 @@ def apply_attention(ctx: Ctx, cfg: ModelConfig, p: dict, x, cos, sin, *,
                                          scale=scale,
                                          mode=ctx.run.kernel_mode)
         out = ctx.cst(out, "act_batch", "act_seq", "act_heads", None)
-        y = jnp.einsum("bse,ed->bsd", out.reshape(B, out.shape[1], H * hd),
-                       p["wo"].astype(c))
-        return ctx.cst(y, "act_batch", "act_seq", "act_embed"), new_cache
+        return out, {"k": ck, "v": cv}
+    new_cache = None
     if cache is not None:
         per_slot = jnp.ndim(cache_index) >= 1
-        if not per_slot and _use_seqsharded_decode(ctx, cfg, x, cache):
-            out, new_cache = _decode_attention_seqsharded(
+        if not per_slot and _use_seqsharded_decode(ctx, cfg, q, cache):
+            return _decode_attention_seqsharded(
                 ctx, cfg, q, cache, k, v, cache_index, scale=scale,
                 local_window=local_window)
-            y = jnp.einsum("bse,ed->bsd",
-                           out.reshape(B, out.shape[1], H * hd),
-                           p["wo"].astype(c))
-            return ctx.cst(y, "act_batch", "act_seq", "act_embed"), new_cache
         if per_slot:
             # continuous batching: every slot writes at its own offset
             # (scattered cache write; OOB rows — done slots — dropped)
@@ -226,7 +262,7 @@ def apply_attention(ctx: Ctx, cfg: ModelConfig, p: dict, x, cos, sin, *,
         cv = ctx.cst(cv, "act_batch", "act_kv_seq", None, None)
         new_cache = {"k": ck, "v": cv}
         kv_len = jnp.broadcast_to(
-            jnp.asarray(cache_index + x.shape[1], jnp.int32), (x.shape[0],))
+            jnp.asarray(cache_index + q.shape[1], jnp.int32), (B,))
         out = ops.decode_attention(q, ck.astype(c), cv.astype(c), kv_len,
                                    softcap=cfg.attn_softcap,
                                    local_window=local_window, scale=scale,
@@ -240,10 +276,7 @@ def apply_attention(ctx: Ctx, cfg: ModelConfig, p: dict, x, cos, sin, *,
                             block_q=ctx.run.attn_block_q,
                             block_kv=ctx.run.attn_block_kv,
                             naive_below=ctx.run.naive_attn_below)
-    out = ctx.cst(out, "act_batch", "act_seq", "act_heads", None)
-    y = jnp.einsum("bse,ed->bsd", out.reshape(B, out.shape[1], H * hd),
-                   p["wo"].astype(c))
-    return ctx.cst(y, "act_batch", "act_seq", "act_embed"), new_cache
+    return ctx.cst(out, "act_batch", "act_seq", "act_heads", None), new_cache
 
 
 def empty_kv_cache(cfg: ModelConfig, batch: int, max_seq: int, dtype,
@@ -397,6 +430,7 @@ def mlp_decls(cfg: ModelConfig, layers: int = 0) -> dict:
     return decls
 
 
+@scoped("mlp")
 def apply_mlp(ctx: Ctx, cfg: ModelConfig, p: dict, x):
     c = ctx.cdtype
     up = jnp.einsum("bsd,df->bsf", x, p["w_up"].astype(c))
@@ -496,6 +530,7 @@ def _moe_expert_ffn(ctx: Ctx, cfg: ModelConfig, p: dict, expert_in,
     return ops.grouped_matmul(jax.nn.silu(gate) * up, wd, mode=mode)
 
 
+@scoped("mlp")
 def apply_moe(ctx: Ctx, cfg: ModelConfig, p: dict, x):
     """Token-choice top-k MoE with capacity-based dispatch.
 
@@ -680,6 +715,7 @@ def _split_mamba(cfg: ModelConfig, zxbcdt):
     return z, xbc, dt
 
 
+@scoped("ssm")
 def apply_mamba(ctx: Ctx, cfg: ModelConfig, p: dict, x, *,
                 ssm_state=None, conv_state=None):
     """Mamba-2 block.  Train/prefill when states are None; single-step decode
@@ -777,6 +813,7 @@ def embed_decls(cfg: ModelConfig) -> dict:
                         "embed", scale=0.02)}
 
 
+@scoped("embed")
 def apply_embed(ctx: Ctx, cfg: ModelConfig, p: dict, tokens):
     emb = jnp.take(p["table"].astype(ctx.cdtype), tokens, axis=0)
     if cfg.embed_scale_by_sqrt_dim:      # gemma-style input scaling

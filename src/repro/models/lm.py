@@ -369,10 +369,11 @@ def _shared_block(ctx, cfg, p, inv_idx, h, emb0, cos, sin, *,
     xcat = jnp.concatenate([h, emb0], axis=-1)
     a_in = L.apply_norm(cfg, p["ln1"], xcat)
     # LoRA delta on q for this invocation
-    la = p["lora_a"][inv_idx].astype(c)
-    lb = p["lora_b"][inv_idx].astype(c)
-    B, S = a_in.shape[:2]
-    q_delta = (a_in @ la @ lb).reshape(B, S, cfg.n_heads, cfg.head_dim)
+    with jax.named_scope("attn_proj"):
+        la = p["lora_a"][inv_idx].astype(c)
+        lb = p["lora_b"][inv_idx].astype(c)
+        B, S = a_in.shape[:2]
+        q_delta = (a_in @ la @ lb).reshape(B, S, cfg.n_heads, cfg.head_dim)
 
     # attention with q = Wq x + LoRA(x)
     attn_p = dict(p["attn"])
@@ -381,11 +382,12 @@ def _shared_block(ctx, cfg, p, inv_idx, h, emb0, cos, sin, *,
         cache_index=cache_index, block_tables=block_tables)
     h = h + out
     m_in = L.apply_norm(cfg, p["ln2"], jnp.concatenate([h, emb0], axis=-1))
-    gate = jnp.einsum("bsd,df->bsf", m_in, p["mlp"]["w_gate"].astype(c))
-    up = jnp.einsum("bsd,df->bsf", m_in, p["mlp"]["w_up"].astype(c))
-    up = ctx.cst(up, "act_batch", "act_seq", "act_mlp")
-    mlp_out = jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
-                         p["mlp"]["w_down"].astype(c))
+    with jax.named_scope("mlp"):
+        gate = jnp.einsum("bsd,df->bsf", m_in, p["mlp"]["w_gate"].astype(c))
+        up = jnp.einsum("bsd,df->bsf", m_in, p["mlp"]["w_up"].astype(c))
+        up = ctx.cst(up, "act_batch", "act_seq", "act_mlp")
+        mlp_out = jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
+                             p["mlp"]["w_down"].astype(c))
     return h + mlp_out, new_cache
 
 
@@ -394,17 +396,30 @@ def _attention_with_qdelta(ctx, cfg, p, x, q_delta, cos, sin, *,
     c = ctx.cdtype
     B, S = x.shape[:2]
     H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    q = jnp.einsum("bsd,de->bse", x, p["wq"].astype(c)
-                   ).reshape(B, S, H, hd) + q_delta
-    k = jnp.einsum("bsd,de->bse", x, p["wk"].astype(c)).reshape(B, S, K, hd)
-    v = jnp.einsum("bsd,de->bse", x, p["wv"].astype(c)).reshape(B, S, K, hd)
-    q = ctx.cst(q, "act_batch", "act_seq", "act_heads", None)
-    if cfg.use_rope:
-        q = L.apply_rope(q, cos, sin, cfg.rotary_pct)
-        k = L.apply_rope(k, cos, sin, cfg.rotary_pct)
+    with jax.named_scope("attn_proj"):
+        q = jnp.einsum("bsd,de->bse", x, p["wq"].astype(c)
+                       ).reshape(B, S, H, hd) + q_delta
+        k = jnp.einsum("bsd,de->bse", x, p["wk"].astype(c)
+                       ).reshape(B, S, K, hd)
+        v = jnp.einsum("bsd,de->bse", x, p["wv"].astype(c)
+                       ).reshape(B, S, K, hd)
+        q = ctx.cst(q, "act_batch", "act_seq", "act_heads", None)
+        if cfg.use_rope:
+            q = L.apply_rope(q, cos, sin, cfg.rotary_pct)
+            k = L.apply_rope(k, cos, sin, cfg.rotary_pct)
+    out, new_cache = _shared_attend(ctx, cfg, q, k, v, cache, cache_index,
+                                    block_tables)
+    return L.attn_out_proj(ctx, p["wo"], out), new_cache
+
+
+@L.scoped("attn_core")
+def _shared_attend(ctx, cfg, q, k, v, cache, cache_index, block_tables):
+    """The shared block's cache write and attention kernel; returns (out
+    (B, S, H, hd), the new cache or None)."""
+    c = ctx.cdtype
+    B, S = q.shape[:2]
     scale = cfg.head_dim ** -0.5
     from repro.kernels import ops
-    new_cache = None
     if cache is not None and block_tables is not None:
         # paged kv_shared pool: same table as the rows keys of the other
         # families (uniform per-slot kv_len), same no-cst rationale as the
@@ -415,24 +430,17 @@ def _attention_with_qdelta(ctx, cfg, p, x, q_delta, cos, sin, *,
         ck, cv = ops.kv_cache_update_paged(cache["k"], cache["v"], k, v,
                                            idx_vec, block_tables,
                                            mode=ctx.run.kernel_mode)
-        new_cache = {"k": ck, "v": cv}
-        kv_len = idx_vec + x.shape[1]
+        kv_len = idx_vec + S
         out = ops.decode_attention_paged(q, ck.astype(c), cv.astype(c),
                                          kv_len, block_tables, scale=scale,
                                          mode=ctx.run.kernel_mode)
-        y = jnp.einsum("bse,ed->bsd", out.reshape(B, out.shape[1], H * hd),
-                       p["wo"].astype(c))
-        return ctx.cst(y, "act_batch", "act_seq", "act_embed"), new_cache
+        return out, {"k": ck, "v": cv}
+    new_cache = None
     if cache is not None:
         per_slot = jnp.ndim(cache_index) >= 1
-        if not per_slot and L._use_seqsharded_decode(ctx, cfg, x, cache):
-            out, new_cache = L._decode_attention_seqsharded(
+        if not per_slot and L._use_seqsharded_decode(ctx, cfg, q, cache):
+            return L._decode_attention_seqsharded(
                 ctx, cfg, q, cache, k, v, cache_index, scale=scale)
-            y = jnp.einsum("bse,ed->bsd",
-                           out.reshape(B, out.shape[1],
-                                       cfg.n_heads * cfg.head_dim),
-                           p["wo"].astype(c))
-            return ctx.cst(y, "act_batch", "act_seq", "act_embed"), new_cache
         if per_slot:
             ck, cv = ops.kv_cache_update(
                 cache["k"], cache["v"], k, v,
@@ -447,7 +455,7 @@ def _attention_with_qdelta(ctx, cfg, p, x, q_delta, cos, sin, *,
         cv = ctx.cst(cv, "act_batch", "act_kv_seq", None, None)
         new_cache = {"k": ck, "v": cv}
         kv_len = jnp.broadcast_to(
-            jnp.asarray(cache_index + x.shape[1], jnp.int32), (x.shape[0],))
+            jnp.asarray(cache_index + S, jnp.int32), (B,))
         out = ops.decode_attention(q, ck.astype(c), cv.astype(c), kv_len,
                                    scale=scale, mode=ctx.run.kernel_mode,
                                    block_kv=ctx.run.attn_block_kv)
@@ -457,9 +465,7 @@ def _attention_with_qdelta(ctx, cfg, p, x, q_delta, cos, sin, *,
                             block_q=ctx.run.attn_block_q,
                             block_kv=ctx.run.attn_block_kv,
                             naive_below=ctx.run.naive_attn_below)
-    y = jnp.einsum("bse,ed->bsd", out.reshape(B, out.shape[1], H * hd),
-                   p["wo"].astype(c))
-    return ctx.cst(y, "act_batch", "act_seq", "act_embed"), new_cache
+    return out, new_cache
 
 
 def _zamba_forward(ctx, cfg, params, h, cos, sin, *, cache=None,
@@ -511,6 +517,7 @@ def unembed_matrix(cfg: ModelConfig, params, dtype):
     return params["unembed"]["w"].astype(dtype)
 
 
+@L.scoped("logits")
 def logits_for(ctx: L.Ctx, cfg: ModelConfig, params, h):
     """Full logits (decode path; small S).  Pad-vocab columns masked."""
     w = unembed_matrix(cfg, params, ctx.cdtype)

@@ -53,6 +53,22 @@ __all__ = ["Request", "ServeEngine", "SlotSnapshot", "serve_phase_tasks",
            "make_decode_chunk_step", "BlockAllocator", "PrefixRegistry"]
 
 
+#: The host spans the engine emits on the profiler's clock, one per
+#: boundary of its work (``_span``).
+SPANS = ("serve.step", "serve.restore", "serve.admit", "serve.prefill",
+         "serve.decode", "serve.fetch", "serve.deliver")
+
+
+def _span(name: str, **args):
+    """Host span ``name`` (one of ``SPANS``) on the profiler's clock.  A
+    ``jax.profiler.TraceAnnotation`` records nothing unless a profiler
+    session is open; in one, it lines up with the device ops of the same
+    trace.  ``serve.step`` is a ``StepTraceAnnotation`` (``step_num``)."""
+    if name == "serve.step":
+        return jax.profiler.StepTraceAnnotation(name, **args)
+    return jax.profiler.TraceAnnotation(name, **args)
+
+
 def serve_phase_tasks(cfg: ModelConfig, batch: int, prompt: int,
                       new_tokens: int, chips: int = 1) -> list[Task]:
     """Prefill vs decode phases with analytic roofline terms — the serving
@@ -209,20 +225,22 @@ def make_decode_chunk_step(cfg: ModelConfig, run: RunConfig, ctx: Ctx,
 
         def body(st):
             cache, cur, index, rem, done, out, t = st
-            # deliver each live slot's pending token into the out buffer
-            out = out.at[:, t].set(jnp.where(done, -1, cur))
-            rem = jnp.where(done, rem, rem - 1)
-            done = done | (rem <= 0)
-            # done slots write at max_seq: OOB rows are DROPPED by the
-            # per-slot cache scatter, so retired lanes cost no state
-            widx = jnp.where(done, max_seq, index)
+            with jax.named_scope("sample"):
+                # deliver each live slot's pending token into the out buffer
+                out = out.at[:, t].set(jnp.where(done, -1, cur))
+                rem = jnp.where(done, rem, rem - 1)
+                done = done | (rem <= 0)
+                # done slots write at max_seq: OOB rows are DROPPED by the
+                # per-slot cache scatter, so retired lanes cost no state
+                widx = jnp.where(done, max_seq, index)
             h, _, cache = lm.forward(
                 ctx, cfg, params, {"tokens": cur[:, None]},
                 cache=cache, cache_index=widx)
             logits = lm.logits_for(ctx, cfg, params, h)
-            nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
-            cur = jnp.where(done, 0, nxt)
-            index = jnp.where(done, index, index + 1)
+            with jax.named_scope("sample"):
+                nxt = jnp.argmax(logits[:, 0], axis=-1).astype(jnp.int32)
+                cur = jnp.where(done, 0, nxt)
+                index = jnp.where(done, index, index + 1)
             return (cache, cur, index, rem, done, out, t + 1)
 
         st = (cache, cur.astype(jnp.int32), index.astype(jnp.int32),
@@ -406,6 +424,7 @@ class ServeEngine:
         self.tracer = tracer if tracer is not None else NULL_TRACER
         self.trace_track = trace_track
         self._vt = 0.0
+        self._step_num = 0          # numbers the serve.step spans
         # jit caches one program per (1, chunk_size) token shape — the
         # chunk_plan power-of-two sizes bound the trace count.  Every step
         # DONATES the cache (pools, block tables) and the decode-lane
@@ -462,9 +481,10 @@ class ServeEngine:
         idx, logits = idx0, None
         for size in chunk_plan(len(tokens), self.prefill_chunk):
             o = idx - idx0
-            toks = jnp.asarray([tokens[o:o + size]], jnp.int32)
-            self._cache, logits = self._prefill_step(
-                self.params, self._cache, toks, sid, idx)
+            with _span("serve.prefill"):
+                toks = jnp.asarray([tokens[o:o + size]], jnp.int32)
+                self._cache, logits = self._prefill_step(
+                    self.params, self._cache, toks, sid, idx)
             idx += size
         return logits
 
@@ -972,11 +992,12 @@ class ServeEngine:
         THIS step (also appended to ``self.finished``)."""
         if not self.pending:
             return []
-        sched = self._sched
-        tr = self.tracer if self.tracer.enabled else None
-        chunk_t0 = self._vt
-        # restored slots first: their work is already paid for — a warm
-        # snapshot install is a cache write, not a prefill program
+        self._step_num += 1
+        with _span("serve.step", step_num=self._step_num):
+            return self._step()
+
+    def _install_restored(self, sched) -> None:
+        """Install waiting warm snapshots into free slots, FCFS."""
         while self._restore_q:
             snap = self._restore_q[0]
             if self.paged and not self._fits_blocks(
@@ -987,41 +1008,53 @@ class ServeEngine:
             if slot is None:
                 break
             self._install_snapshot(self._restore_q.popleft(), slot.sid)
+
+    def _step(self) -> list[Request]:
+        sched = self._sched
+        tr = self.tracer if self.tracer.enabled else None
+        # restored slots first: their work is already paid for — a warm
+        # snapshot install is a cache write, not a prefill program
+        if self._restore_q:
+            with _span("serve.restore"):
+                self._install_restored(sched)
         # one phase entry per admitted request = one prefill program
         # run under the prefill cap (back-to-back entries coalesce the
         # cap write; the modeled measurement accounts each prefill)
         can_admit = self._can_admit if self.paged else None
         for slot in sched.admit_ready(can_admit=can_admit):
             req = slot.request
-            plen = len(req.prompt)
-            # phase cost in CHUNK PROGRAMS actually run: a shared prefix
-            # skips its chunks, a long prompt costs more than a short one
-            skip = self._shared_credit(
-                req.prompt, min(req.prefix_len, plen - 1)) if self.paged \
-                else 0
-            n_calls = len(chunk_plan(plen - skip, self.prefill_chunk))
-            with self._phase("prefill", calls=n_calls) as rec:
-                if self.paged:
-                    logits = self._admit_paged(req, slot.sid)
-                else:
-                    self._cache, logits = self._prefill_into_slot(
-                        self._cache, req, slot.sid)
-            self._cur, self._index, self._rem, self._done = self._admit_fn(
-                self._cur, self._index, self._rem, self._done, logits,
-                slot.sid, len(slot.request.prompt),
-                slot.request.max_new_tokens)
+            with _span("serve.admit", uid=req.uid):
+                plen = len(req.prompt)
+                # phase cost in CHUNK PROGRAMS actually run: a shared
+                # prefix skips its chunks, a long prompt costs more than
+                # a short one
+                skip = self._shared_credit(
+                    req.prompt, min(req.prefix_len, plen - 1)) \
+                    if self.paged else 0
+                n_calls = len(chunk_plan(plen - skip, self.prefill_chunk))
+                with self._phase("prefill", calls=n_calls) as rec:
+                    if self.paged:
+                        logits = self._admit_paged(req, slot.sid)
+                    else:
+                        self._cache, logits = self._prefill_into_slot(
+                            self._cache, req, slot.sid)
+                (self._cur, self._index, self._rem,
+                 self._done) = self._admit_fn(
+                    self._cur, self._index, self._rem, self._done, logits,
+                    slot.sid, plen, req.max_new_tokens)
             if tr is not None:
                 m = getattr(rec, "modeled", None)
                 dt = m.runtime if m is not None else 1.0
                 tr.span("prefill", self._vt, self._vt + dt,
                         self.trace_track, cat="phase",
-                        args={"uid": slot.request.uid,
+                        args={"uid": req.uid,
                               "energy_j": m.energy if m is not None
                               else 0.0})
                 self._vt += dt
         uids = [s.request.uid for s in sched.active()] \
             if tr is not None else None
-        with self._phase("decode", calls=self.decode_chunk) as rec:
+        with self._phase("decode", calls=self.decode_chunk) as rec, \
+                _span("serve.decode"):
             (self._cache, self._cur, self._index, self._rem, self._done,
              out, _) = self._decode_fn(
                 self.params, self._cache, self._cur, self._index,
@@ -1034,23 +1067,23 @@ class ServeEngine:
                     args={"uids": uids,
                           "energy_j": m.energy if m is not None else 0.0})
             self._vt += dt
-            tr.span("engine.chunk", chunk_t0, self._vt, self.trace_track,
-                    cat="chunk", args={"active": len(uids)})
-        out_host = self._fetch(out)           # the chunk's ONE sync
+        with _span("serve.fetch"):
+            out_host = self._fetch(out)       # the chunk's ONE sync
         self.sync_count += 1
-        now = time.perf_counter() - self._t0
-        newly: list[Request] = []
-        for slot in sched.active():
-            row = out_host[slot.sid]
-            fresh = [int(t) for t in row[:_valid_len(row)]]
-            slot.request.generated.extend(fresh)
-            slot.emitted += len(fresh)
-            if slot.emitted >= slot.request.max_new_tokens:
-                self.completion_s[slot.request.uid] = now
-                newly.append(sched.release(slot))
-                if self.paged:
-                    self._release_slot_blocks(slot.sid)
-        self.finished.extend(newly)
+        with _span("serve.deliver"):
+            now = time.perf_counter() - self._t0
+            newly: list[Request] = []
+            for slot in sched.active():
+                row = out_host[slot.sid]
+                fresh = [int(t) for t in row[:_valid_len(row)]]
+                slot.request.generated.extend(fresh)
+                slot.emitted += len(fresh)
+                if slot.emitted >= slot.request.max_new_tokens:
+                    self.completion_s[slot.request.uid] = now
+                    newly.append(sched.release(slot))
+                    if self.paged:
+                        self._release_slot_blocks(slot.sid)
+            self.finished.extend(newly)
         return newly
 
     def generate(self, requests: list[Request]) -> list[Request]:
