@@ -34,6 +34,11 @@ _VMEM_LIMIT = 96 * 1024 * 1024
 # cap on a decode block's (rows * kv heads) columns: the f32 logits and
 # probabilities are (K * Sq * g, cols) each
 _MAX_DECODE_COLS = 256
+# cap on the elements of one paged-decode step's f32 logits tile,
+# (K * Sq * g) x (P * bs * K): it sets the P pool pages a step walks
+# (on a v5e, a 32-slot minitron decode step ran its kernel 16% faster at
+# this cap, 32 pages a step, than at half of it, and no faster at twice)
+_MAX_DECODE_LOGITS = 128 * 1024
 
 
 def _online_softmax_step(logits, v, m_ref, l_ref, acc_ref):
@@ -166,58 +171,139 @@ def flash_attention(q, k, v, *, causal=True, local_window=None, softcap=None,
 
 # ---------------------------------------------------------------------------
 # paged flash-decode: a short query block (1..chunk new tokens per slot)
-# against KV rows that live in a pool of fixed-size blocks
+# against KV rows that live in a pool of fixed-size pages
 # (n_blocks, bs, K, D) — optionally stacked over layers — read through a
 # per-slot block table.  It is the serving runtime's decode step AND its
-# chunked-prefill attention.  The table rides as a SCALAR PREFETCH argument
-# (PrefetchScalarGridSpec): the k/v BlockSpec index_maps dereference it, so
-# the DMA engine fetches exactly the slot's blocks and the dense view is
-# never materialized (the vLLM paged-attention idiom).
+# chunked-prefill attention.
 #
-# One grid step holds one pool block for ALL kv heads: a (bs, K, D) block is
-# tile-legal for any K, where a single head's (bs, 1, D) slice is not.  The
-# q rows of every head are stacked into one (K * Sq * g, D) operand, the
-# block into (bs * K, D), and a block-diagonal head mask keeps each q row on
-# its own kv head's columns.  That costs K x the MXU work of a per-head
-# product, which decode (memory-bound) does not notice.
+# The pools stay in HBM (memory_space=ANY); the table, the valid lengths
+# and the layer ride as SCALAR PREFETCH.  Grid step (b, i) covers slot b's
+# logical pages [i*P, (i+1)*P): the kernel copies each LIVE page of the
+# group (first row below kv_len) with its own async copy into one half of a
+# double-buffered (2, P*bs, K, D) VMEM scratch, and starts the NEXT live
+# step's copies — the slot's next group, else the next live slot's first —
+# before it computes the current group.  A step past the slot's last live
+# page issues no copy and no compute, so pages mapped for tokens to come
+# and the parking block are never read.  Because a copy crosses from one
+# slot to the next, both grid dimensions are "arbitrary".
+#
+# A step holds its pages for ALL kv heads: a (bs, K, D) page is tile-legal
+# for any K, where a single head's (bs, 1, D) slice is not.  The q rows of
+# every head are stacked into one (K * Sq * g, D) operand, the group into
+# (P * bs * K, D), and a block-diagonal head mask keeps each q row on its
+# own kv head's columns.  That costs K x the MXU work of a per-head
+# product, which decode (memory-bound) does not notice.  P is the largest
+# power of two whose f32 logits tile fits _MAX_DECODE_LOGITS: 32 pages of
+# 16 rows for a minitron decode step, one for a 64- or 256-row prefill
+# chunk.
 # ---------------------------------------------------------------------------
 
-def _decode_paged_kernel(layer_ref, len_ref, bt_ref, q_ref, k_ref, v_ref,
-                         o_ref, m_ref, l_ref, acc_ref, *, scale, softcap,
-                         local_window, block_size, n_blk, sq, g, n_kv):
-    del layer_ref, bt_ref                   # consumed by the index_maps
-    b = pl.program_id(0)
-    ib = pl.program_id(1)
+def _pages_per_step(rows, block_size, n_kv, max_blocks):
+    """Largest power of two P <= max_blocks whose (rows, P * block_size *
+    n_kv) f32 logits tile holds at most _MAX_DECODE_LOGITS elements; at
+    least 1."""
+    p = 1
+    while (2 * p <= max_blocks
+           and rows * 2 * p * block_size * n_kv <= _MAX_DECODE_LOGITS):
+        p *= 2
+    return p
 
-    @pl.when(ib == 0)
+
+def _decode_paged_kernel(layer_ref, len_ref, bt_ref, q_ref, k_hbm, v_hbm,
+                         o_ref, k_buf, v_buf, k_sem, v_sem, state, m_ref,
+                         l_ref, acc_ref, *, scale, softcap, local_window,
+                         block_size, pages, n_steps, sq, g, n_kv):
+    b = pl.program_id(0)
+    i = pl.program_id(1)
+    n_slots = pl.num_programs(0)
+    bs = block_size
+    s_logical = bt_ref.shape[1] * bs
+
+    def live_pages(slot):
+        # pages whose first row is below kv_len; none for a done slot
+        n = len_ref[slot]
+        return jnp.where(n > s_logical, 0, jnp.maximum(n, 0) + bs - 1) // bs
+
+    def page_copies(slot, step, buf, act):
+        """act(copy) for each of the slot's live pages in grid step
+        ``step``, K and V, into half ``buf`` of the scratch."""
+        first = step * pages
+        count = jnp.minimum(live_pages(slot) - first, pages)
+
+        def one(j, carry):
+            page = bt_ref[slot, first + j]
+            rows = pl.ds(pl.multiple_of(j * bs, bs), bs)
+            act(pltpu.make_async_copy(k_hbm.at[layer_ref[0], page],
+                                      k_buf.at[buf, rows], k_sem.at[buf]))
+            act(pltpu.make_async_copy(v_hbm.at[layer_ref[0], page],
+                                      v_buf.at[buf, rows], v_sem.at[buf]))
+            return carry
+
+        jax.lax.fori_loop(0, count, one, 0)
+
+    @pl.when((b == 0) & (i == 0))
+    def _first_step():
+        # state: [scratch half of the next live step, its copies started?]
+        state[0] = 0
+        state[1] = 0
+        # the last group of a slot leaves pages past its live ones stale;
+        # their probabilities are exactly 0, which keeps them out of the
+        # sums only while they hold finite values
+        k_buf[...] = jnp.zeros_like(k_buf)
+        v_buf[...] = jnp.zeros_like(v_buf)
+
+    @pl.when(i == 0)
     def _init():
         m_ref[...] = jnp.full_like(m_ref, NEG_INF)
         l_ref[...] = jnp.zeros_like(l_ref)
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    kv_len = len_ref[b]
-    k_start = ib * block_size          # LOGICAL position of this block
+    n_live = live_pages(b)
 
-    @pl.when(k_start < kv_len)
+    @pl.when(i * pages < n_live)
     def _body():
-        d = k_ref.shape[-1]
-        k = k_ref[...].reshape(block_size * n_kv, d)     # row = pos*K + head
-        v = v_ref[...].reshape(block_size * n_kv, d)
+        buf = state[0]
+
+        @pl.when(state[1] == 0)
+        def _start_own():
+            page_copies(b, i, buf, lambda c: c.start())
+
+        # the next live step: this slot's next group, else the first group
+        # of the next slot with a live page
+        more = (i + 1) * pages < n_live
+        later = jax.lax.while_loop(
+            lambda s: (s < n_slots)
+            & (live_pages(jnp.minimum(s, n_slots - 1)) == 0),
+            lambda s: s + 1, b + 1)
+        has_next = more | (later < n_slots)
+
+        @pl.when(has_next)
+        def _prefetch():
+            page_copies(jnp.where(more, b, later), jnp.where(more, i + 1, 0),
+                        1 - buf, lambda c: c.start())
+
+        state[0] = 1 - buf
+        state[1] = has_next.astype(jnp.int32)
+        page_copies(b, i, buf, lambda c: c.wait())
+
+        d = k_buf.shape[-1]
+        k = k_buf[buf].reshape(pages * bs * n_kv, d)   # row = pos*K + head
+        v = v_buf[buf].reshape(pages * bs * n_kv, d)
         logits = _scaled_logits(q_ref[...], k, scale, softcap)
         # q row r: kv head r // (sq*g), query position kv_len - sq + the
         # row's index within its head group // g (the sq new tokens sit at
         # the END of the valid kv window; causal within the chunk)
         r = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 0)
         c = jax.lax.broadcasted_iota(jnp.int32, logits.shape, 1)
-        q_pos = kv_len - sq + (r % (sq * g)) // g
-        k_pos = k_start + c // n_kv
+        q_pos = len_ref[b] - sq + (r % (sq * g)) // g
+        k_pos = i * pages * bs + c // n_kv     # LOGICAL position
         mask = (r // (sq * g) == c % n_kv) & (k_pos <= q_pos)
         if local_window is not None:
             mask &= k_pos > q_pos - local_window
         _online_softmax_step(jnp.where(mask, logits, NEG_INF), v,
                              m_ref, l_ref, acc_ref)
 
-    @pl.when(ib == n_blk - 1)
+    @pl.when(i == n_steps - 1)
     def _finalize():
         denom = jnp.maximum(l_ref[...], 1e-30)
         o_ref[...] = (acc_ref[...] / denom).astype(o_ref.dtype)
@@ -239,9 +325,11 @@ def flash_decode_paged(q, k_pool, v_pool, kv_len, block_tables, layer=None,
     """q: (B, Sq, H, D); pools: (n_blocks, bs, K, D), or (L, n_blocks, bs,
     K, D) read at ``layer``; kv_len: (B,) int32 valid length INCLUDING the
     Sq new tokens; block_tables: (B, max_blocks) int32 — slot b's logical
-    rows [i*bs, (i+1)*bs) live in pool block ``block_tables[b, i]``.  The kv
-    grid dimension walks the slot's table; blocks past kv_len skip their
-    compute."""
+    rows [i*bs, (i+1)*bs) live in pool block ``block_tables[b, i]``.  Only
+    a slot's live pages (first row below kv_len) are read.  A slot whose
+    kv_len exceeds the table's logical length (max_blocks * bs) reads
+    nothing and gets zeros: the done-slot convention, whose cache write
+    ``cache_update_paged`` dropped whole."""
     B, Sq, H, D = q.shape
     k_pool, layer_arr = _layer_stack(k_pool, layer)
     v_pool, _ = _layer_stack(v_pool, layer)
@@ -250,25 +338,30 @@ def flash_decode_paged(q, k_pool, v_pool, kv_len, block_tables, layer=None,
     scale = scale if scale is not None else D ** -0.5
     g = H // K
     rows = K * Sq * g
+    pages = _pages_per_step(rows, bs, K, max_blocks)
+    n_steps = pl.cdiv(max_blocks, pages)
     # rows grouped by kv head: (B, K * Sq * g, D)
     qg = q.reshape(B, Sq, K, g, D).transpose(0, 2, 1, 3, 4) \
           .reshape(B, rows, D)
 
     kernel = functools.partial(_decode_paged_kernel, scale=scale,
                                softcap=softcap, local_window=local_window,
-                               block_size=bs, n_blk=max_blocks, sq=Sq, g=g,
-                               n_kv=K)
+                               block_size=bs, pages=pages, n_steps=n_steps,
+                               sq=Sq, g=g, n_kv=K)
     q_spec = pl.BlockSpec((None, rows, D),
-                          lambda b, ib, ly, ln, bt: (b, 0, 0))
-    kv_spec = pl.BlockSpec((None, None, bs, K, D),
-                           lambda b, ib, ly, ln, bt:
-                           (ly[0], bt[b, ib], 0, 0, 0))
+                          lambda b, i, ly, ln, bt: (b, 0, 0))
+    pool_spec = pl.BlockSpec(memory_space=pl.ANY)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(B, max_blocks),
-        in_specs=[q_spec, kv_spec, kv_spec],
+        grid=(B, n_steps),
+        in_specs=[q_spec, pool_spec, pool_spec],
         out_specs=q_spec,
         scratch_shapes=[
+            pltpu.VMEM((2, pages * bs, K, D), k_pool.dtype),
+            pltpu.VMEM((2, pages * bs, K, D), v_pool.dtype),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SemaphoreType.DMA((2,)),
+            pltpu.SMEM((2,), jnp.int32),
             pltpu.VMEM((rows, 1), jnp.float32),
             pltpu.VMEM((rows, 1), jnp.float32),
             pltpu.VMEM((rows, D), jnp.float32),
@@ -280,7 +373,7 @@ def flash_decode_paged(q, k_pool, v_pool, kv_len, block_tables, layer=None,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((B, rows, D), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "arbitrary"),
+            dimension_semantics=("arbitrary", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT),
         interpret=interpret,
     )(layer_arr, kv_len.astype(jnp.int32), block_tables.astype(jnp.int32),

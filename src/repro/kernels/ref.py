@@ -166,11 +166,18 @@ def decode_attention_paged_ref(q, k_pool, v_pool, kv_len, block_tables, *,
     the dense (B, max_blocks*bs, K, D) view, then run the ragged-kv_len
     decode attention.  Identical shapes and reduction order to the dense
     path, so outputs are bit-identical to ``decode_attention_ref`` over a
-    dense cache holding the same valid rows."""
+    dense cache holding the same valid rows.
+
+    A slot whose kv_len exceeds the table's logical length
+    (max_blocks * bs rows) attends to nothing and gets zeros: the
+    done-slot convention, whose write ``kv_cache_update_paged_ref``
+    drops whole."""
     k_dense = paged_gather_ref(k_pool, block_tables)
     v_dense = paged_gather_ref(v_pool, block_tables)
-    return decode_attention_ref(q, k_dense, v_dense, kv_len, scale=scale,
-                                softcap=softcap, local_window=local_window)
+    out = decode_attention_ref(q, k_dense, v_dense, kv_len, scale=scale,
+                               softcap=softcap, local_window=local_window)
+    done = kv_len > k_dense.shape[1]
+    return jnp.where(done[:, None, None, None], 0, out).astype(out.dtype)
 
 
 def kv_cache_update_paged_ref(k_pool, v_pool, k_new, v_new, index,
@@ -180,7 +187,9 @@ def kv_cache_update_paged_ref(k_pool, v_pool, k_new, v_new, index,
     destinations each slot's table maps its rows [index, index+Sn) to.
     A slot whose write would cross its table's logical end
     (max_blocks * bs rows) is dropped WHOLE — the same done-slot
-    convention as the dense ``kv_cache_update_ref``."""
+    convention as the dense ``kv_cache_update_ref``; such a slot's
+    attention (``decode_attention_paged_ref``) reads nothing and is
+    zero."""
     B, Sn = k_new.shape[:2]
     n_blocks, bs = k_pool.shape[:2]
     max_blocks = block_tables.shape[1]

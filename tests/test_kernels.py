@@ -8,7 +8,8 @@ import pytest
 
 from repro.configs.base import RunConfig
 from repro.kernels import ops, ref
-from repro.kernels.flash_attention import (cache_update, cache_update_paged,
+from repro.kernels.flash_attention import (_pages_per_step, cache_update,
+                                           cache_update_paged,
                                            flash_attention, flash_decode,
                                            flash_decode_paged)
 from repro.kernels.grouped_matmul import grouped_matmul
@@ -220,6 +221,89 @@ def test_paged_ops_read_and_write_one_layer_of_a_stack(mode):
     for other in (0, 2):
         np.testing.assert_array_equal(got_k[other], k_stack[other])
         np.testing.assert_array_equal(got_v[other], v_stack[other])
+
+
+def _live_walk_case(Sq, bs, lens_in_groups):
+    """A scattered pool whose blocks are NaN except each slot's live pages
+    (first row below kv_len): pages mapped for tokens to come and blocks
+    no table maps are never to be read.  ``lens_in_groups(step_rows,
+    logical)`` gives the slots' kv_len from the kernel's rows per grid
+    step.  Returns (q, clean pools, poisoned pools, kv_len, tables)."""
+    H, K, D = 32, 8, 16                 # 8 kv heads: a few pages a step
+    rows = K * Sq * (H // K)
+    # room for three grid steps of pages per slot
+    max_blocks = 3 * _pages_per_step(rows, bs, K, 1 << 20)
+    pages = _pages_per_step(rows, bs, K, max_blocks)
+    lens = lens_in_groups(pages * bs, max_blocks * bs)
+    B = len(lens)
+    k_pool, v_pool, tables = _paged_pools(B * max_blocks + 3, bs, K, D, B,
+                                          max_blocks)
+    live = np.zeros(k_pool.shape[0], bool)
+    for b, n in enumerate(lens):
+        if n <= max_blocks * bs:
+            live[np.asarray(tables)[b, :-(-n // bs)]] = True
+    keep = jnp.asarray(live)[:, None, None, None]
+    poisoned = tuple(jnp.where(keep, p, jnp.nan) for p in (k_pool, v_pool))
+    q = jax.random.normal(KEY, (B, Sq, H, D))
+    return (q, (k_pool, v_pool), poisoned, jnp.array(lens, jnp.int32),
+            tables)
+
+
+@pytest.mark.parametrize("bs", [8, 16])
+@pytest.mark.parametrize("Sq", [1, 4])
+def test_flash_decode_paged_reads_only_live_pages(Sq, bs):
+    """Every block that is not one of a slot's live pages holds NaN; the
+    kernel still equals the oracle on the clean pool, over ragged lengths:
+    the shortest, ending on a grid step's last page, one row past it,
+    several steps with a partial last page, the full table, and a done
+    slot (kv_len past the table), which is zero in both."""
+    q, clean, poisoned, kv_len, tables = _live_walk_case(
+        Sq, bs, lambda step, logical: [Sq, step, step + 1, 2 * step + 3,
+                                       logical, logical + 1])
+    assert tables.shape[1] * bs > 2 * int(kv_len[1])   # several steps
+    out = flash_decode_paged(q, *poisoned, kv_len, tables, interpret=True)
+    exp = ref.decode_attention_paged_ref(q, *clean, kv_len, tables)
+    assert not np.isnan(np.asarray(out)).any()
+    np.testing.assert_allclose(out, exp, atol=2e-5, rtol=2e-5)
+    np.testing.assert_array_equal(out[-1], 0)
+
+
+@pytest.mark.parametrize("past", [1, 8, 1000])
+@pytest.mark.parametrize("impl", ["kernel", "oracle"])
+def test_flash_decode_paged_done_slot_is_zero(impl, past):
+    """A slot whose kv_len exceeds the table's logical length (a done
+    slot, whose cache write was dropped whole) attends to nothing and
+    returns zeros; the live slot beside it is unaffected."""
+    q, clean, _, kv_len, tables = _live_walk_case(
+        1, 8, lambda step, logical: [step + 5, logical + past])
+    if impl == "kernel":
+        out = flash_decode_paged(q, *clean, kv_len, tables, interpret=True)
+    else:
+        out = ref.decode_attention_paged_ref(q, *clean, kv_len, tables)
+    np.testing.assert_array_equal(out[1], 0)
+    live = ref.decode_attention_ref(
+        q[:1], ref.paged_gather_ref(clean[0], tables[:1]),
+        ref.paged_gather_ref(clean[1], tables[:1]), kv_len[:1])
+    np.testing.assert_allclose(out[:1], live, atol=2e-5, rtol=2e-5)
+
+
+@pytest.mark.parametrize("softcap,window", [(20.0, None), (None, 24),
+                                            (30.0, 40)])
+@pytest.mark.parametrize("Sq", [1, 4])
+def test_flash_decode_paged_features_through_the_walk(Sq, softcap, window):
+    """Logit soft-capping and a sliding window over slots that span
+    several grid steps, with every non-live block NaN: a window that
+    starts inside a later step leaves whole earlier steps masked."""
+    q, clean, poisoned, kv_len, tables = _live_walk_case(
+        Sq, 8, lambda step, logical: [Sq + 2, step + 1, 2 * step + 5,
+                                      logical])
+    out = flash_decode_paged(q, *poisoned, kv_len, tables, softcap=softcap,
+                             local_window=window, interpret=True)
+    exp = ref.decode_attention_paged_ref(q, *clean, kv_len, tables,
+                                         softcap=softcap,
+                                         local_window=window)
+    assert not np.isnan(np.asarray(out)).any()
+    np.testing.assert_allclose(out, exp, atol=2e-5, rtol=2e-5)
 
 
 def test_kernel_mode_follows_the_platform(monkeypatch):

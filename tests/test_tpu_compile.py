@@ -69,9 +69,11 @@ def test_flash_decode_compiles(chip, sq):
                      q, cache, cache, lens)
 
 
-@pytest.mark.parametrize("sq", [1, 256])
+@pytest.mark.parametrize("sq", [1, 8, 64, 256])
 def test_flash_decode_paged_compiles(chip, sq):
-    """The engine's path: a layer-stacked pool read at a traced layer."""
+    """The engine's path: a layer-stacked pool read at a traced layer.
+    The chunk sizes reach every group size the kernel picks (32 pages of
+    16 rows at a decode step down to one at a 256-row prefill chunk)."""
     c = MINITRON
     max_blocks = 4096 // BLOCK
     q = _sds((BATCH, sq, c.n_heads, c.head_dim), jnp.bfloat16, chip)
