@@ -13,11 +13,18 @@ approximates:
   * the SSD chunk is the one the program picks for the call's length.
 
 Every function takes the ``model`` section of a configuration file (a
-plain dict), never the program's objects, so no later change to the
-program can move these counts.
+plain dict, or ``reference.model_view``), never the program's objects, so
+no later change to the program can move these counts.  What depends on
+the model's structure -- the weights a token multiplies through, how many
+layers attend and how many run the SSD scan, the width of B and C, the
+weights under the ``ssm`` scope -- comes from the model's kind module
+(``chipbench/reference/<kind>.py``); the arithmetic here is the same for
+every kind.
 """
 
 from __future__ import annotations
+
+from chipbench import reference
 
 BF16 = 2
 F32 = 4
@@ -31,36 +38,23 @@ def ssm_heads(m: dict) -> int:
     return _d_inner(m) // m["ssm_headdim"]
 
 
-def n_super(m: dict) -> int:
-    """Shared-attention invocations of a hybrid; its attention layers."""
-    return m["n_layers"] // m["shared_attn_period"]
-
-
 def attention_layers(m: dict) -> int:
-    if m["family"] == "hybrid":
-        return n_super(m)
-    return m["n_layers"]
+    return reference.kind(m).attention_layers(m)
+
+
+def ssm_layers(m: dict) -> int:
+    return reference.kind(m).ssm_layers(m)
 
 
 def matmul_weights_per_token(m: dict) -> int:
     """Weights one token multiplies through once, embeddings and the
     unembedding excluded (logits are counted apart)."""
-    d, f = m["d_model"], m["d_ff"]
-    H, K, hd = m["n_heads"], m["n_kv_heads"], m["head_dim"]
-    if m["family"] == "dense":
-        attn = d * H * hd + 2 * d * K * hd + H * hd * d
-        mlp = (3 if m["mlp"] in ("swiglu", "geglu") else 2) * d * f
-        return m["n_layers"] * (attn + mlp)
-    if m["family"] == "hybrid":
-        din, N = _d_inner(m), m["ssm_state"]
-        Hs = ssm_heads(m)
-        mamba = d * (2 * din + 2 * N + Hs) + din * d
-        d2, r = 2 * d, m["shared_attn_lora"]
-        shared = (d2 * H * hd + 2 * d2 * K * hd + H * hd * d   # q k v o
-                  + d2 * r + r * H * hd                         # LoRA on q
-                  + 2 * d2 * f + f * d)                         # SwiGLU MLP
-        return m["n_layers"] * mamba + n_super(m) * shared
-    raise ValueError(f"no counts for family {m['family']!r}")
+    return reference.kind(m).matmul_weights_per_token(m)
+
+
+def ssm_scope_weights(m: dict) -> int:
+    """Matmul weights the program runs under its ``ssm`` scope."""
+    return reference.kind(m).ssm_scope_weights(m)
 
 
 def attention_pairs(offset: int, rows: int) -> int:
@@ -84,21 +78,26 @@ def ssd_chunk(m: dict, rows: int) -> int:
 def ssd_flops(m: dict, rows: int) -> float:
     """SSD scan of one ``rows``-row call, every Mamba layer: per token and
     head 2 * (QN + QP + 2PN) (``hw/flops._ssd_flops_fwd``)."""
-    if m["family"] not in ("ssm", "hybrid"):
+    layers = ssm_layers(m)
+    if not layers:
         return 0.0
     Q = ssd_chunk(m, rows) if rows > 1 else 1
     P, N = m["ssm_headdim"], m["ssm_state"]
     per = 2.0 * (Q * N + Q * P + 2.0 * P * N)
-    return per * ssm_heads(m) * rows * m["n_layers"]
+    return per * ssm_heads(m) * rows * layers
 
 
 def ssd_bytes(m: dict, rows: int) -> float:
     """Bytes one ``rows``-row SSD kernel call must move, every Mamba layer:
     x, dt, B, C in and y out (bf16), the carried and final state (f32)."""
+    layers = ssm_layers(m)
+    if not layers:
+        return 0.0
     Hs, P, N = ssm_heads(m), m["ssm_headdim"], m["ssm_state"]
-    per_layer = (BF16 * rows * (2 * Hs * P + Hs + 2 * N)
+    bc = reference.kind(m).bc_width(m)
+    per_layer = (BF16 * rows * (2 * Hs * P + Hs + bc)
                  + F32 * 2 * Hs * P * N)
-    return per_layer * m["n_layers"]
+    return per_layer * layers
 
 
 def logits_flops(m: dict, positions: int) -> float:

@@ -155,7 +155,7 @@ class Step:
 class Record:
     name: str
     conf: dict
-    model: dict
+    model: dict                  # reference.model_view of the file
     serve: dict
     peaks: dict
     seed: int
@@ -168,7 +168,7 @@ class Record:
     drain_end: float = 0.0
     counters: dict = dataclasses.field(default_factory=dict)
     compile_s: float = 0.0
-    trace: object = None         # chipbench.trace.Reduced, --trace 1 only
+    trace: object = None         # chipbench.scopes.Scoped, --trace 1 only
 
     def window_steps(self, traced: bool = False) -> list:
         return [s for s in self.steps if s.phase == "window"
@@ -546,8 +546,9 @@ def build(spec: dict, seed: int, seconds: float, t_start: float,
     conf, tparams = spec["conf"], spec["traffic"]
     serve = conf["serve"]
     cfg = model_config(conf)
-    rec = Record(name=spec["cell"]["name"], conf=conf, model=conf["model"],
-                 serve=serve, peaks=peaks, seed=seed, seconds=seconds)
+    rec = Record(name=spec["cell"]["name"], conf=conf,
+                 model=R.model_view(conf), serve=serve, peaks=peaks,
+                 seed=seed, seconds=seconds)
     print(f"[setup] {rec.name}: device {dev.device_kind} x {len(devices)}; "
           f"compile cache {cache}", flush=True)
 
@@ -635,8 +636,8 @@ def run(spec: dict, seed: int, seconds: float, trace: bool,
     del engine
     gc.collect()
     if tracer.path:
-        from chipbench import trace as T
-        rec.trace = T.reduce(tracer.path)
+        from chipbench import scopes
+        rec.trace = scopes.reduce(tracer.path)
     tracer.cleanup()
 
     sample = check_sample(rec, traffic.rng_for(seed, 7))

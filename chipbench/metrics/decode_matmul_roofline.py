@@ -24,14 +24,10 @@ FEED = (scopes.PLUMBING, scopes.UNSCOPED)
 
 def weights(m: dict) -> int:
     """Weights of the attn_proj, mlp and logits scopes: every matmul weight
-    of a dense model; a hybrid's Mamba projections run in its ssm scope."""
-    w = flops.matmul_weights_per_token(m) + m["d_model"] * m["vocab"]
-    if m["family"] == "hybrid":
-        d, din = m["d_model"], m["ssm_expand"] * m["d_model"]
-        hs = flops.ssm_heads(m)
-        w -= m["n_layers"] * (d * (2 * din + 2 * m["ssm_state"] + hs)
-                              + din * d)
-    return w
+    but those the model's kind runs in its ssm scope, and the
+    unembedding."""
+    return (flops.matmul_weights_per_token(m) + m["d_model"] * m["vocab"]
+            - flops.ssm_scope_weights(m))
 
 
 def read(rec):

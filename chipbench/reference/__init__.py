@@ -14,11 +14,32 @@ wrong cache row, mask or state serves tokens far below it.
 in the program's place at the next precision down (fp8 where the program
 holds bf16, ``common.rnd``): at each position the token the fp8 forward
 ranks first, read against the float32 reference.
+
+Each kind of model is a module of its own, ``<kind>.py`` in this
+directory (``KINDS``), found by name as the metric readers are: the
+configuration file's top-level ``reference`` key names it, and without
+that key the kind is the model's ``family``.  The name stays out of the
+file's ``model`` section, which the program gets whole
+(``harness.model_config``); the benchmark's view of a model
+(``model_view``) is that section with ``reference`` added.  A kind module
+exports
+  * ``hidden(model, params, tokens, mode)``: the final-normed hidden
+    states of one sequence;
+  * the counts that depend on the model's structure
+    (``chipbench/flops.py`` reads them): ``matmul_weights_per_token``,
+    ``attention_layers``, ``ssm_layers``, ``bc_width`` (the B and C
+    columns of one Mamba-2 layer's input) and ``ssm_scope_weights`` (the
+    matmul weights the program runs under its ``ssm`` scope);
+  * ``check(model)``: raises on any setting of the program's model (its
+    ``ModelConfig`` as a dict) that the module does not compute.
 """
 
 from __future__ import annotations
 
 import functools
+import importlib.util
+import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -29,17 +50,48 @@ from chipbench.reference import common as C
 BUCKET = 1024     # sequences are padded to a multiple of this (causal: the
                   # padding after a position never reaches it)
 LOGIT_BLOCK = 256
+KINDS = os.path.dirname(os.path.abspath(__file__))
+KEY = "reference"
+EXPORTS = ("hidden", "matmul_weights_per_token", "attention_layers",
+           "ssm_layers", "bc_width", "ssm_scope_weights", "check")
+_NAME = re.compile(r"^[A-Za-z_][A-Za-z0-9_]{0,63}$")
+
+
+def kind_name(conf: dict) -> str:
+    """The kind module a configuration file names, else its family."""
+    return conf.get(KEY, conf["model"]["family"])
+
+
+def model_view(conf: dict) -> dict:
+    """The configuration's ``model`` section with its kind under ``KEY``."""
+    return dict(conf["model"], **{KEY: kind_name(conf)})
+
+
+@functools.lru_cache(maxsize=None)
+def _load(kinds: str, name: str):
+    """``<kinds>/<name>.py``, loaded once."""
+    path = os.path.join(kinds, f"{name}.py")
+    if not _NAME.match(name) or not os.path.exists(path):
+        raise KeyError(f"no reference kind {name!r}: {path} does not exist")
+    spec = importlib.util.spec_from_file_location(
+        f"chipbench.reference.{name}", path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    missing = [f for f in EXPORTS if not callable(getattr(mod, f, None))]
+    if missing:
+        raise AttributeError(f"reference kind {path} lacks {missing}")
+    return mod
+
+
+def kind(model: dict):
+    """The kind module of ``model`` (``model_view``, or a bare ``model``
+    section, whose kind is its family), found in ``KINDS``."""
+    return _load(KINDS, model.get(KEY, model["family"]))
 
 
 def hidden(model: dict, params, tokens, mode: str = "f32"):
-    if model["family"] == "dense":
-        from chipbench.reference.dense import hidden as h
-    elif model["family"] == "hybrid":
-        from chipbench.reference.hybrid import hidden as h
-    else:
-        raise ValueError(f"no reference for family {model['family']!r}")
     with jax.default_matmul_precision("highest"):
-        return h(model, params, tokens, mode)
+        return kind(model).hidden(model, params, tokens, mode)
 
 
 def unembedding(params):

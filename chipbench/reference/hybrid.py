@@ -13,6 +13,8 @@ The Mamba-2 mixer is written as its recurrence, one position at a time:
 after a causal depthwise convolution (window ``ssm_conv``) and SiLU over
 (x, B, C), with dt = softplus(dt_raw + dt_bias), A = -exp(A_log), and a
 gated RMSNorm rmsnorm(y * silu(z)) before the output projection.
+
+Also this kind's counts (``chipbench/flops.py``) and ``check``.
 """
 
 from __future__ import annotations
@@ -115,3 +117,74 @@ def hidden(model: dict, params, tokens, mode: str = "f32"):
             h = block(shared, sh["lora_a"][inv], sh["lora_b"][inv], h, e,
                       pos)
     return C.norm(model, params["final_norm"], h)
+
+
+# ---------------------------------------------------------------------------
+# counts (chipbench/flops.py) and the settings this reference computes
+# ---------------------------------------------------------------------------
+
+def n_super(m: dict) -> int:
+    """Shared-attention invocations; the model's attention layers."""
+    return m["n_layers"] // m["shared_attn_period"]
+
+
+def _mamba_weights(m: dict) -> int:
+    """in_proj (z, x, B, C, dt) and out_proj of one Mamba-2 layer."""
+    d, din = m["d_model"], m["ssm_expand"] * m["d_model"]
+    hs = din // m["ssm_headdim"]
+    return d * (2 * din + bc_width(m) + hs) + din * d
+
+
+def matmul_weights_per_token(m: dict) -> int:
+    """Weights one token multiplies through once, embeddings and the
+    unembedding excluded (logits are counted apart): a shared block that
+    runs n times counts n times."""
+    d, f = m["d_model"], m["d_ff"]
+    H, K, hd = m["n_heads"], m["n_kv_heads"], m["head_dim"]
+    d2, r = 2 * d, m["shared_attn_lora"]
+    shared = (d2 * H * hd + 2 * d2 * K * hd + H * hd * d   # q k v o
+              + d2 * r + r * H * hd                         # LoRA on q
+              + 2 * d2 * f + f * d)                         # SwiGLU MLP
+    return m["n_layers"] * _mamba_weights(m) + n_super(m) * shared
+
+
+def attention_layers(m: dict) -> int:
+    return n_super(m)
+
+
+def ssm_layers(m: dict) -> int:
+    return m["n_layers"]
+
+
+def bc_width(m: dict) -> int:
+    """B and C, one group of ``ssm_state`` columns each."""
+    return 2 * m["ssm_state"]
+
+
+def ssm_scope_weights(m: dict) -> int:
+    """The Mamba projections, which the program runs in its ssm scope."""
+    return m["n_layers"] * _mamba_weights(m)
+
+
+# settings of the program's model this reference does not compute, at the
+# one value it assumes; and the values it computes of the others it reads
+FIXED = {"family": "hybrid", "attn_softcap": None, "final_softcap": None,
+         "query_scale": None, "post_norms": False,
+         "embed_scale_by_sqrt_dim": False, "mrope_sections": None,
+         "use_rope": True, "causal": True, "local_window": None,
+         "layer_pattern": "global", "n_experts": 0, "frontend": None,
+         "norm": "rmsnorm"}
+CHOICES = {"mlp": ("swiglu", "squared_relu")}
+POSITIVE = ("ssm_state", "shared_attn_period", "shared_attn_lora")
+
+
+def check(model: dict) -> None:
+    """Raise on a setting of the program's model this reference does not
+    compute."""
+    bad = {k: model[k] for k, v in FIXED.items() if model.get(k, v) != v}
+    bad.update({k: model[k] for k, ok in CHOICES.items()
+                if model[k] not in ok})
+    bad.update({k: model[k] for k in POSITIVE if not model[k] > 0})
+    if bad:
+        raise ValueError(f"{__name__}: the reference does not compute "
+                         f"{bad}")

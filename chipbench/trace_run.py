@@ -10,10 +10,10 @@ stdout line is one JSON object: the engine steps that ended in the first
 ``harness.TRACE_SECONDS`` of the window and their rate -- the same count
 with ``--trace 0`` and ``--trace 1`` gives the cost of tracing -- and, with
 ``--trace 1``, the trace's path under <dir>, the seconds ``trace.reduce``
-and ``scopes.reduce`` took, every per-layer metric of the cell together
-with those that read the scopes and spans, device seconds by program and
-each kernel's by program, and the device idle time inside the harness's
-``chipbench.step`` spans by the innermost ``serve.*`` span.
+and ``scopes.reduce`` took, every per-layer metric of the cell, device
+seconds by program and each kernel's by program, and the device idle time
+inside the harness's ``chipbench.step`` spans by the innermost
+``serve.*`` span.
 stderr gets the readers' splits and ``scopes.summary`` of the trace.
 """
 
@@ -31,8 +31,6 @@ sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(
     __file__))))
 
 KERNELS = ("flash_decode_paged", "cache_update_paged")
-SCOPED_METRICS = ("decode_matmul_roofline", "decode_plumbing_share.batch",
-                  "engine_idle_ms.batch")
 
 
 def first_steps(rec, seconds: float) -> dict:
@@ -81,8 +79,8 @@ def main(argv=None) -> int:
         rec.trace = r = scopes.reduce(tracer.path)
         out["reduce_s"] = t1 - t0
         out["scoped_reduce_s"] = time.perf_counter() - t1
-        names = [m["name"] for m in spec["per_layer"]] + list(SCOPED_METRICS)
-        out["metrics"] = {n: metrics.read(n, rec) for n in names}
+        out["metrics"] = {m["name"]: metrics.read(m["name"], rec)
+                          for m in spec["per_layer"]}
         mods = sorted({m[0] for m in r.modules})
         out["device"] = {"busy_s": r.busy_s(), "window_s": r.window_s,
                          "module_s": {m: r.module_s(m) for m in mods},
