@@ -24,7 +24,9 @@ class ModelConfig:
     head_dim: int = 128
     # --- norm / mlp / logits ---
     norm: str = "rmsnorm"           # rmsnorm | layernorm | layernorm1p
-    mlp: str = "swiglu"             # swiglu | geglu | squared_relu | gelu
+    mlp: str = "swiglu"             # swiglu | geglu | geglu_exact |
+    #                                 squared_relu | gelu (geglu: tanh GELU)
+    norm_eps: float = 1e-6
     attn_softcap: float | None = None
     final_softcap: float | None = None
     query_scale: float | None = None      # gemma2 query_pre_attn_scalar
@@ -51,9 +53,21 @@ class ModelConfig:
     ssm_expand: int = 2
     ssm_conv: int = 4
     ssm_chunk: int = 128
+    ssm_ngroups: int = 1                  # B/C groups; head h reads group
+    #                                       h // (ssm_heads // ssm_ngroups)
     # --- hybrid (zamba2) ---
-    shared_attn_period: int = 0           # apply shared attn block every N
+    # the repo's block: one shared block after every N Mamba layers, its
+    # output added to the residual, a per-use LoRA on q
+    shared_attn_period: int = 0
     shared_attn_lora: int = 0             # per-invocation LoRA rank
+    # the published Zamba2 block: shared blocks run before the Mamba layers
+    # of these ids (as the published config.json lists them, so a list)
+    # and feed those layers' input; ``num_mem_blocks`` blocks are used in
+    # turn, each use with its own rank-``adapter_rank`` adapters on the MLP
+    # and its own output projection
+    hybrid_layer_ids: list[int] | None = None
+    num_mem_blocks: int = 0
+    adapter_rank: int = 0
     # --- modality frontend (stub: precomputed embeddings) ---
     frontend: str | None = None           # audio | vision
     frontend_dim: int = 0
@@ -175,6 +189,11 @@ def reduced(cfg: ModelConfig, **overrides: Any) -> ModelConfig:
         small.update(ssm_state=16, ssm_headdim=16, ssm_chunk=16)
     if cfg.shared_attn_period:
         small.update(shared_attn_period=2)
+    if cfg.hybrid_layer_ids:
+        # irregular gaps, a layer before the first use and after the last,
+        # and every block used at least twice in turn
+        small.update(n_layers=8, hybrid_layer_ids=[1, 3, 4, 6],
+                     adapter_rank=8)
     if cfg.frontend:
         small.update(frontend_dim=min(cfg.frontend_dim, 64) or 64,
                      vision_tokens=min(cfg.vision_tokens, 16))

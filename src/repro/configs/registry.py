@@ -11,7 +11,8 @@ import dataclasses
 
 from repro.configs import (gemma2_2b, hubert_xlarge, llama32_3b,
                            mamba2_370m, minitron_4b, nemotron4_15b, olmoe,
-                           phi35_moe, qwen2_vl_72b, zamba2_1p2b)
+                           phi35_moe, qwen2_vl_72b, zamba2_1p2b,
+                           zamba2_7b)
 from repro.configs.base import SHAPES, ModelConfig, RunConfig, ShapeConfig
 
 _MODULES = {
@@ -19,6 +20,7 @@ _MODULES = {
     "olmoe-1b-7b": olmoe,
     "mamba2-370m": mamba2_370m,
     "zamba2-1.2b": zamba2_1p2b,
+    "zamba2-7b": zamba2_7b,
     "minitron-4b": minitron_4b,
     "llama3.2-3b": llama32_3b,
     "gemma2-2b": gemma2_2b,
@@ -54,7 +56,7 @@ def cell_supported(arch: str, shape: str) -> tuple[bool, str]:
 
 
 def all_cells() -> list[tuple[str, str, bool, str]]:
-    """All 40 (arch, shape) cells with support status."""
+    """Every (arch, shape) cell with its support status."""
     out = []
     for arch in ARCH_IDS:
         for shape in SHAPES:

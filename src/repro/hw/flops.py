@@ -56,8 +56,7 @@ def _attention_flops_fwd(cfg: ModelConfig, batch: int, s_q: int,
     area = s_q * s_kv * (0.5 if (cfg.causal and s_q == s_kv) else 1.0)
     per_layer = 4.0 * batch * area * cfg.n_heads * cfg.head_dim
     if cfg.family == "hybrid":
-        n_super, _, _ = lm.zamba_structure(cfg)
-        return per_layer * n_super
+        return per_layer * len(lm.shared_block_uses(cfg))
     if cfg.layer_pattern == "local_global":
         # local layers see a clamped window
         win = min(cfg.local_window or s_kv, s_kv)
@@ -114,13 +113,14 @@ def _cache_bytes(cfg: ModelConfig, batch: int, seq: int) -> float:
             win = min(cfg.local_window or seq, seq)
             kv_layers = cfg.n_layers / 2 * (1 + win / seq)
     elif cfg.family == "hybrid":
-        kv_layers = lm.zamba_structure(cfg)[0]
+        kv_layers = len(lm.shared_block_uses(cfg))
     kv = 2.0 * kv_layers * batch * seq * cfg.n_kv_heads * cfg.head_dim * 2
     ssm = 0.0
     if cfg.family in ("ssm", "hybrid"):
         ssm = (cfg.n_layers * batch
                * (cfg.ssm_heads * cfg.ssm_headdim * cfg.ssm_state * 4
-                  + (cfg.ssm_conv - 1) * (cfg.d_inner + 2 * cfg.ssm_state)
+                  + (cfg.ssm_conv - 1)
+                  * (cfg.d_inner + 2 * cfg.ssm_ngroups * cfg.ssm_state)
                   * 2))
     return kv + ssm
 

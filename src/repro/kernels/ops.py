@@ -85,25 +85,41 @@ def kv_cache_update(k_cache, v_cache, k_new, v_new, index, *, mode=None):
     return ref.kv_cache_update_ref(k_cache, v_cache, k_new, v_new, index)
 
 
+def pad_head(x, width: int):
+    """x (..., D) with zero columns up to ``width``."""
+    pad = width - x.shape[-1]
+    return x if not pad else jax.numpy.pad(
+        x, [(0, 0)] * (x.ndim - 1) + [(0, pad)])
+
+
 def decode_attention_paged(q, k_pool, v_pool, kv_len, block_tables,
                            layer=None, *, softcap=None, local_window=None,
                            scale=None, mode=None):
     """Decode-step / chunked-prefill attention over a PAGED cache: the
-    pools (n_blocks, bs, K, D) — or a layer stack (L, n_blocks, bs, K, D)
-    read at ``layer`` — hold fixed-size blocks and each slot reads its
+    pools (n_blocks, bs, K, Dp) — or a layer stack (L, n_blocks, bs, K,
+    Dp) read at ``layer`` — hold fixed-size blocks and each slot reads its
     rows through its ``block_tables`` row ((B, max_blocks) int32), ragged
-    up to kv_len (B,).  The reference path gathers the dense per-slot view
-    and reuses the dense decode oracle (bit-identical by construction);
-    the Pallas path gathers block-by-block through the table via scalar
-    prefetch, never materializing the dense view."""
+    up to kv_len (B,).  Rows may be wider than q's head size D (zero
+    columns, ``layers.paged_head_dim``): the kernel gets q padded to Dp
+    and the output cut back to D.  The reference path gathers the dense
+    per-slot view and reuses the dense decode oracle (bit-identical by
+    construction); the Pallas path gathers block-by-block through the
+    table via scalar prefetch, never materializing the dense view."""
+    D = q.shape[-1]
+    scale = scale if scale is not None else D ** -0.5
+
+    def cut(x):                     # rows back to D columns
+        return x if x.shape[-1] == D else x[..., :D]
+
     kernel, interpret = _pallas(mode)
     if kernel:
         from repro.kernels import flash_attention
-        return flash_attention.flash_decode_paged(
-            q, k_pool, v_pool, kv_len, block_tables, layer, softcap=softcap,
-            local_window=local_window, scale=scale, interpret=interpret)
+        return cut(flash_attention.flash_decode_paged(
+            pad_head(q, k_pool.shape[-1]), k_pool, v_pool, kv_len,
+            block_tables, layer, softcap=softcap, local_window=local_window,
+            scale=scale, interpret=interpret))
     return ref.decode_attention_paged_ref(
-        q, _layer(k_pool, layer), _layer(v_pool, layer), kv_len,
+        q, cut(_layer(k_pool, layer)), cut(_layer(v_pool, layer)), kv_len,
         block_tables, softcap=softcap, local_window=local_window,
         scale=scale)
 
@@ -116,7 +132,10 @@ def kv_cache_update_paged(k_pool, v_pool, k_new, v_new, index, block_tables,
     a slot whose write crosses its table's logical end is dropped whole
     (done-slot semantics, index = max_seq).  The engine guarantees write
     destinations are PRIVATE blocks (copy-on-write happens at admission),
-    so no two slots scatter into the same row.  Returns (k_pool', v_pool')."""
+    so no two slots scatter into the same row.  Rows narrower than the
+    pool's are written with zero columns.  Returns (k_pool', v_pool')."""
+    k_new = pad_head(k_new, k_pool.shape[-1])
+    v_new = pad_head(v_new, v_pool.shape[-1])
     kernel, interpret = _pallas(mode)
     if kernel:
         from repro.kernels import flash_attention

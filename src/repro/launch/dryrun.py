@@ -109,8 +109,17 @@ def _make_step(cfg, run, ctx, shape):
 
 def _variant_ks(cfg) -> tuple[int, ...]:
     if cfg.family == "hybrid":
-        p = cfg.shared_attn_period
-        return (p, 2 * p, p + 1)
+        # depths a, a + 1 with one use of the shared blocks, b with two
+        import dataclasses
+
+        from repro.models.lm import shared_block_uses
+
+        def uses(k):
+            return len(shared_block_uses(dataclasses.replace(cfg,
+                                                             n_layers=k)))
+        a = next(k for k in range(1, cfg.n_layers + 1) if uses(k) == 1)
+        b = next(k for k in range(a, cfg.n_layers + 1) if uses(k) == 2)
+        return (a, a + 1, b)
     if cfg.layer_pattern == "local_global":
         return (2, 4)
     return (1, 2)
@@ -152,13 +161,12 @@ def corrected_costs(arch: str, shape_name: str, mesh, rules,
         c = {k: costs[k][field] for k in ks}
         L = cfg.n_layers
         if cfg.family == "hybrid":
-            p = cfg.shared_attn_period
-            from repro.models.lm import zamba_structure
-            n_super, _, trailing = zamba_structure(cfg)
-            sb = c[2 * p] - c[p]
-            mb = c[p + 1] - c[p]
-            outer = c[p] - sb
-            return outer + n_super * sb + trailing * mb
+            from repro.models.lm import shared_block_uses
+            a, a1, b = ks
+            mb = c[a1] - c[a]                       # one Mamba layer
+            sb = c[b] - c[a] - (b - a) * mb         # one use of a block
+            outer = c[a] - a * mb - sb
+            return outer + L * mb + len(shared_block_uses(cfg)) * sb
         if cfg.layer_pattern == "local_global":
             pair = c[4] - c[2]
             outer = c[2] - pair

@@ -82,8 +82,9 @@ def norm_decls(cfg: ModelConfig, layers: int = 0,
 
 
 @scoped("norm")
-def apply_norm(cfg: ModelConfig, p: dict, x, eps: float = 1e-6):
+def apply_norm(cfg: ModelConfig, p: dict, x):
     xf = x.astype(jnp.float32)
+    eps = cfg.norm_eps
     if cfg.norm == "rmsnorm":
         var = jnp.mean(xf * xf, axis=-1, keepdims=True)
         out = xf * jax.lax.rsqrt(var + eps) * p["scale"].astype(jnp.float32)
@@ -98,12 +99,14 @@ def apply_norm(cfg: ModelConfig, p: dict, x, eps: float = 1e-6):
     return out.astype(x.dtype)
 
 
-def rmsnorm_gated(scale, y, z, eps: float = 1e-6):
-    """Mamba-2 RMSNormGated: rmsnorm(y * silu(z)) * scale."""
+def rmsnorm_gated(scale, y, z, eps: float = 1e-6, groups: int = 1):
+    """Mamba-2 RMSNormGated: rmsnorm(y * silu(z)) * scale, the mean square
+    taken over each of ``groups`` equal groups of channels apart."""
     yf = y.astype(jnp.float32) * jax.nn.silu(z.astype(jnp.float32))
-    var = jnp.mean(yf * yf, axis=-1, keepdims=True)
-    return (yf * jax.lax.rsqrt(var + eps)
-            * scale.astype(jnp.float32)).astype(y.dtype)
+    yg = yf.reshape(yf.shape[:-1] + (groups, -1))
+    var = jnp.mean(yg * yg, axis=-1, keepdims=True)
+    yf = (yg * jax.lax.rsqrt(var + eps)).reshape(yf.shape)
+    return (yf * scale.astype(jnp.float32)).astype(y.dtype)
 
 
 # ===========================================================================
@@ -173,13 +176,15 @@ def attention_decls(cfg: ModelConfig, layers: int = 0,
 
 def apply_attention(ctx: Ctx, cfg: ModelConfig, p: dict, x, cos, sin, *,
                     local_window=None, cache=None, cache_index=None,
-                    x_kv=None, block_tables=None, layer=None):
+                    x_kv=None, block_tables=None, layer=None, q_delta=None):
     """x: (B, S, d_in).  With ``cache`` (dict k/v (B, Smax, K, hd)) performs a
     decode step and returns (y, new_cache).  With ``block_tables``
     ((B, max_blocks) int32) the cache leaves are PAGED pools
     (n_blocks, bs, K, hd) and every read/write goes through the table;
     with ``layer`` too they are the whole layer stack
-    (L, n_blocks, bs, K, hd), read and updated in place at that layer."""
+    (L, n_blocks, bs, K, hd), read and updated in place at that layer.
+    ``q_delta`` (B, S, H, hd) is added to the query projection (the repo
+    zamba2 block's per-use LoRA)."""
     c = ctx.cdtype
     x_kv = x if x_kv is None else x_kv
     B, S = x.shape[:2]
@@ -187,6 +192,8 @@ def apply_attention(ctx: Ctx, cfg: ModelConfig, p: dict, x, cos, sin, *,
     with jax.named_scope("attn_proj"):
         q = jnp.einsum("bsd,de->bse", x, p["wq"].astype(c)
                        ).reshape(B, S, H, hd)
+        if q_delta is not None:
+            q = q + q_delta
         k = jnp.einsum("bsd,de->bse", x_kv, p["wk"].astype(c)
                        ).reshape(B, S, K, hd)
         v = jnp.einsum("bsd,de->bse", x_kv, p["wv"].astype(c)
@@ -296,20 +303,28 @@ KV_CACHE_AXES = {"k": ("layers", "act_batch", "act_kv_seq", None, None),
                  "v": ("layers", "act_batch", "act_kv_seq", None, None)}
 
 
+def paged_head_dim(cfg: ModelConfig) -> int:
+    """Width of a head's row in a paged pool: ``head_dim`` rounded up to
+    128 lanes, the extra columns zero.  The paged kernels copy whole pages
+    by DMA, which the TPU compiler takes only for a minor dim that is a
+    multiple of 128 (zamba2-7b's 224 is not)."""
+    return -(-cfg.head_dim // 128) * 128
+
+
 def empty_paged_kv_cache(cfg: ModelConfig, n_blocks: int, block_size: int,
                          dtype, layers: int = 0):
-    """Paged KV pool: (n_blocks, block_size, K, hd) per layer — a shared
-    arena of fixed-size blocks addressed through per-slot block tables
-    instead of a dense (batch, max_seq, ...) lane per slot."""
-    shape = _stack((n_blocks, block_size, cfg.n_kv_heads, cfg.head_dim),
-                   layers)
+    """Paged KV pool: (n_blocks, block_size, K, paged_head_dim) per layer —
+    a shared arena of fixed-size blocks addressed through per-slot block
+    tables instead of a dense (batch, max_seq, ...) lane per slot."""
+    shape = _stack((n_blocks, block_size, cfg.n_kv_heads,
+                    paged_head_dim(cfg)), layers)
     return {"k": jnp.zeros(shape, dtype), "v": jnp.zeros(shape, dtype)}
 
 
 def abstract_paged_kv_cache(cfg: ModelConfig, n_blocks: int, block_size: int,
                             dtype, layers: int = 0):
-    shape = _stack((n_blocks, block_size, cfg.n_kv_heads, cfg.head_dim),
-                   layers)
+    shape = _stack((n_blocks, block_size, cfg.n_kv_heads,
+                    paged_head_dim(cfg)), layers)
     sds = jax.ShapeDtypeStruct(shape, dtype)
     return {"k": sds, "v": sds}
 
@@ -418,29 +433,38 @@ def _decode_attention_seqsharded(ctx: Ctx, cfg: ModelConfig, q, cache,
 # dense MLPs
 # ===========================================================================
 
+GATED_MLPS = ("swiglu", "geglu", "geglu_exact")
+
+
 def mlp_decls(cfg: ModelConfig, layers: int = 0) -> dict:
     d, f = cfg.d_model, cfg.d_ff
     decls = {
         "w_up": PD(_stack((d, f), layers), _saxes(("embed", "mlp"), layers)),
         "w_down": PD(_stack((f, d), layers), _saxes(("mlp", "embed"), layers)),
     }
-    if cfg.mlp in ("swiglu", "geglu"):
+    if cfg.mlp in GATED_MLPS:
         decls["w_gate"] = PD(_stack((d, f), layers),
                              _saxes(("embed", "mlp"), layers))
     return decls
 
 
 @scoped("mlp")
-def apply_mlp(ctx: Ctx, cfg: ModelConfig, p: dict, x):
+def apply_mlp(ctx: Ctx, cfg: ModelConfig, p: dict, x, adapter=None):
+    """``adapter`` (a (d, r), b (r, 2 d_ff)): a low-rank term (x a) b added
+    to [gate | up] (the published Zamba2 shared MLP's per-use adapters)."""
     c = ctx.cdtype
     up = jnp.einsum("bsd,df->bsf", x, p["w_up"].astype(c))
+    if cfg.mlp in GATED_MLPS:
+        gate = jnp.einsum("bsd,df->bsf", x, p["w_gate"].astype(c))
+    if adapter is not None:
+        a, b = adapter
+        d_gate, d_up = jnp.split(x @ a.astype(c) @ b.astype(c), 2, axis=-1)
+        gate, up = gate + d_gate, up + d_up
     up = ctx.cst(up, "act_batch", "act_seq", "act_mlp")
     if cfg.mlp == "swiglu":
-        gate = jnp.einsum("bsd,df->bsf", x, p["w_gate"].astype(c))
         h = jax.nn.silu(gate) * up
-    elif cfg.mlp == "geglu":
-        gate = jnp.einsum("bsd,df->bsf", x, p["w_gate"].astype(c))
-        h = jax.nn.gelu(gate, approximate=True) * up
+    elif cfg.mlp in ("geglu", "geglu_exact"):
+        h = jax.nn.gelu(gate, approximate=cfg.mlp == "geglu") * up
     elif cfg.mlp == "squared_relu":
         r = jax.nn.relu(up)
         h = r * r
@@ -671,12 +695,26 @@ def _moe_shard_map(ctx: Ctx, cfg: ModelConfig, p: dict, x, top_p, top_e):
 # mamba-2 block (SSD)
 # ===========================================================================
 
+def _dt_apart(cfg: ModelConfig) -> bool:
+    """Whether dt's columns of the input projection are a matrix of their
+    own.  Stacked over layers, one (d, 2 d_inner + 2GN + H) matrix is not a
+    multiple of 128 lanes wide, and the TPU compiler then copies the whole
+    stack into another layout on every call (4.3 GB at zamba2-7b); [z |
+    xBC] alone is.  The published Zamba2 (``num_mem_blocks``) keeps dt
+    apart; the repo's zamba2 keeps the one matrix its reference
+    (``chipbench/reference/hybrid.py``) reads, and mamba2 with it."""
+    return cfg.num_mem_blocks > 0
+
+
 def mamba_decls(cfg: ModelConfig, layers: int = 0) -> dict:
     d, din = cfg.d_model, cfg.d_inner
-    H, N, G = cfg.ssm_heads, cfg.ssm_state, 1
+    H, N, G = cfg.ssm_heads, cfg.ssm_state, cfg.ssm_ngroups
     conv_dim = din + 2 * G * N
-    d_in_proj = 2 * din + 2 * G * N + H
-    return {
+    d_in_proj = 2 * din + 2 * G * N + (0 if _dt_apart(cfg) else H)
+    decls = {} if not _dt_apart(cfg) else {
+        "dt_proj": PD(_stack((d, H), layers),
+                      _saxes(("embed", "ssm_heads"), layers))}
+    return decls | {
         "in_proj": PD(_stack((d, d_in_proj), layers),
                       _saxes(("embed", "ssm_inner"), layers)),
         "conv_w": PD(_stack((cfg.ssm_conv, conv_dim), layers),
@@ -707,28 +745,68 @@ def _causal_conv(x, w, b):
 
 
 def _split_mamba(cfg: ModelConfig, zxbcdt):
-    din, N, H = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads
-    G = 1
+    din, N, G = cfg.d_inner, cfg.ssm_state, cfg.ssm_ngroups
     z = zxbcdt[..., :din]
     xbc = zxbcdt[..., din:din + din + 2 * G * N]
     dt = zxbcdt[..., din + din + 2 * G * N:]
     return z, xbc, dt
 
 
+def _state_heads(s, H: int, P: int):
+    """A cached SSM state (B, N, H * P) as (B, H, P, N)."""
+    B, N = s.shape[:2]
+    return s.reshape(B, N, H, P).transpose(0, 2, 3, 1)
+
+
+def _state_lanes(s):
+    """A (B, H, P, N) SSM state as the cache holds it, (B, N, H * P)."""
+    B, H, P, N = s.shape
+    return s.transpose(0, 3, 1, 2).reshape(B, N, H * P)
+
+
+def _ssd_step_lanes(state, x, dt, A, Bm, Cm, D):
+    """One decode step of the SSM recurrence (``kernels.ref.
+    ssd_decode_step``'s equations) on the cache's layout: state (Bb, N,
+    H * P) float32, heads x head size on lanes, the state's N on
+    sublanes; x (Bb, H, P), dt (Bb, H), B/C (Bb, G, N).  Returns (y (Bb,
+    H, P), state)."""
+    f32 = jnp.float32
+    Bb, H, P = x.shape
+    per = H // Bm.shape[1] * P             # lanes of one group's heads
+
+    def lanes(m):                          # (Bb, G, N) -> (Bb, N, H * P)
+        return jnp.repeat(jnp.swapaxes(m.astype(f32), 1, 2), per, axis=-1)
+
+    dtf = dt.astype(f32)
+    decay = jnp.repeat(jnp.exp(A[None] * dtf), P, axis=-1)       # (Bb, HP)
+    xdt = (x.astype(f32) * dtf[..., None]).reshape(Bb, H * P)
+    state = state * decay[:, None] + xdt[:, None] * lanes(Bm)
+    y = jnp.sum(state * lanes(Cm), axis=1).reshape(Bb, H, P)
+    y = y + x.astype(f32) * D[None, :, None]
+    return y.astype(x.dtype), state
+
+
 @scoped("ssm")
 def apply_mamba(ctx: Ctx, cfg: ModelConfig, p: dict, x, *,
                 ssm_state=None, conv_state=None):
     """Mamba-2 block.  Train/prefill when states are None; single-step decode
-    when (ssm_state, conv_state) are provided (S must be 1).
+    when (ssm_state, conv_state) are provided (S must be 1).  The SSM state
+    is (B, N, H * P): with N and P both 64, a (B, H, P, N) float32 state
+    fills half of each 128-lane row, and the TPU compiler copies the whole
+    stack into another layout around the decode loop.
 
     Returns (y, (new_ssm_state, new_conv_state))."""
     c = ctx.cdtype
     B, S, _ = x.shape
     din, N, H, P = cfg.d_inner, cfg.ssm_state, cfg.ssm_heads, cfg.ssm_headdim
-    G = 1
+    G = cfg.ssm_ngroups
     zxbcdt = jnp.einsum("bsd,de->bse", x, p["in_proj"].astype(c))
     zxbcdt = ctx.cst(zxbcdt, "act_batch", "act_seq", "act_ssm")
-    z, xbc, dt_raw = _split_mamba(cfg, zxbcdt)
+    if "dt_proj" in p:
+        z, xbc = zxbcdt[..., :din], zxbcdt[..., din:]
+        dt_raw = jnp.einsum("bsd,dh->bsh", x, p["dt_proj"].astype(c))
+    else:
+        z, xbc, dt_raw = _split_mamba(cfg, zxbcdt)
 
     conv_w = p["conv_w"].astype(c)
     conv_b = p["conv_b"].astype(c)
@@ -760,30 +838,37 @@ def apply_mamba(ctx: Ctx, cfg: ModelConfig, p: dict, x, *,
     A = -jnp.exp(p["A_log"].astype(jnp.float32))
 
     if decode:
-        from repro.kernels.ref import ssd_decode_step
-        y1, last_state = ssd_decode_step(
+        y1, last_state = _ssd_step_lanes(
             ssm_state, xs[:, 0], dt[:, 0].astype(c), A, Bm[:, 0], Cm[:, 0],
-            D=p["D"].astype(jnp.float32))
+            p["D"].astype(jnp.float32))
         y = y1[:, None]
     else:
         chunk = min(cfg.ssm_chunk, S)
         while S % chunk:
             chunk -= 1
+        h0 = None if ssm_state is None else _state_heads(ssm_state, H, P)
         y, last_state = ops.ssd(xs, dt.astype(c), A, Bm, Cm,
-                                D=p["D"].astype(jnp.float32), h0=ssm_state,
+                                D=p["D"].astype(jnp.float32), h0=h0,
                                 chunk=chunk, mode=ctx.run.kernel_mode)
+        if ssm_state is not None:
+            last_state = _state_lanes(last_state)
     y = y.reshape(B, S, din)
-    y = rmsnorm_gated(p["norm"], y, z)
+    y = rmsnorm_gated(p["norm"], y, z, cfg.norm_eps, G)
     out = jnp.einsum("bse,ed->bsd", y, p["out_proj"].astype(c))
     out = ctx.cst(out, "act_batch", "act_seq", "act_embed")
     return out, (last_state, new_conv_state)
 
 
+def _conv_dim(cfg: ModelConfig) -> int:
+    """Channels of the causal conv: x, then B and C of every group."""
+    return cfg.d_inner + 2 * cfg.ssm_ngroups * cfg.ssm_state
+
+
 def empty_mamba_state(cfg: ModelConfig, batch: int, dtype, layers: int = 0):
     H, P, N = cfg.ssm_heads, cfg.ssm_headdim, cfg.ssm_state
-    conv_dim = cfg.d_inner + 2 * cfg.ssm_state
+    conv_dim = _conv_dim(cfg)
     return {
-        "ssm": jnp.zeros(_stack((batch, H, P, N), layers), jnp.float32),
+        "ssm": jnp.zeros(_stack((batch, N, H * P), layers), jnp.float32),
         "conv": jnp.zeros(_stack((batch, cfg.ssm_conv - 1, conv_dim), layers),
                           dtype),
     }
@@ -791,16 +876,16 @@ def empty_mamba_state(cfg: ModelConfig, batch: int, dtype, layers: int = 0):
 
 def abstract_mamba_state(cfg: ModelConfig, batch: int, dtype, layers: int = 0):
     H, P, N = cfg.ssm_heads, cfg.ssm_headdim, cfg.ssm_state
-    conv_dim = cfg.d_inner + 2 * cfg.ssm_state
+    conv_dim = _conv_dim(cfg)
     return {
-        "ssm": jax.ShapeDtypeStruct(_stack((batch, H, P, N), layers),
+        "ssm": jax.ShapeDtypeStruct(_stack((batch, N, H * P), layers),
                                     jnp.float32),
         "conv": jax.ShapeDtypeStruct(
             _stack((batch, cfg.ssm_conv - 1, conv_dim), layers), dtype),
     }
 
 
-MAMBA_STATE_AXES = {"ssm": ("layers", "act_batch", "ssm_heads", None, None),
+MAMBA_STATE_AXES = {"ssm": ("layers", "act_batch", None, "act_ssm"),
                     "conv": ("layers", "act_batch", None, "act_ssm")}
 
 

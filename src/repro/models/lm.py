@@ -1,4 +1,4 @@
-"""Unified LM assembly for all 10 assigned architectures.
+"""Unified LM assembly for every registry architecture.
 
 One declarative parameter tree + one forward covering:
 
@@ -6,8 +6,10 @@ One declarative parameter tree + one forward covering:
               post-norms / softcaps / local-global alternation (gemma2)
   moe         every-layer token-choice top-k MoE (phi3.5-moe, olmoe)
   ssm         mamba-2 (SSD) attention-free stack (mamba2-370m)
-  hybrid      mamba-2 backbone + SHARED attention block applied periodically
-              with per-invocation LoRA (zamba2)
+  hybrid      mamba-2 backbone + SHARED attention blocks: the repo's block
+              applied periodically with per-invocation LoRA (zamba2-1.2b),
+              or the published Zamba2 blocks at listed layer ids
+              (zamba2-7b)
   audio       encoder-only transformer over precomputed frame embeddings
               (hubert-xlarge; frontend is a stub per the assignment)
   vlm         decoder with M-RoPE; precomputed patch embeddings merged into
@@ -54,8 +56,9 @@ def _tf_layer_decls(cfg: ModelConfig, n: int, moe: bool) -> dict:
 
 
 def _shared_attn_decls(cfg: ModelConfig, n_inv: int) -> dict:
-    """Zamba2 shared transformer block over concat(h, emb) (width 2*d_model),
-    plus per-invocation LoRA adapters on the q projection."""
+    """The repo's zamba2 shared transformer block over concat(h, emb)
+    (width 2*d_model), plus per-invocation LoRA adapters on the q
+    projection."""
     d2 = 2 * cfg.d_model
     r = cfg.shared_attn_lora or 32
     return {
@@ -74,13 +77,37 @@ def _shared_attn_decls(cfg: ModelConfig, n_inv: int) -> dict:
     }
 
 
-def zamba_structure(cfg: ModelConfig) -> tuple[int, int, int]:
-    """(n_super, mamba_per_super, trailing) with
-    n_super*mamba_per_super + trailing == n_layers."""
+def _published_shared_decls(cfg: ModelConfig, n_uses: int) -> dict:
+    """The published Zamba2 shared blocks: ``num_mem_blocks`` attention+MLP
+    blocks, attention over concat(h, emb) (width 2*d_model), the MLP over
+    the normed attention output; and per use its MLP adapters and its
+    output projection.  Each block and each use is a leaf group of its
+    own, so a use reads its weights whole, with no slice of a stack."""
+    d, f, r = cfg.d_model, cfg.d_ff, cfg.adapter_rank
+
+    def block():
+        return {"ln1": L.norm_decls(cfg, d=2 * d),
+                "attn": L.attention_decls(cfg, d_in=2 * d),
+                "ln2": L.norm_decls(cfg), "mlp": L.mlp_decls(cfg)}
+
+    def use():
+        return {"adapter_a": PD((d, r), ("embed", "lora")),
+                "adapter_b": PD((r, 2 * f), ("lora", "mlp")),
+                "out": PD((d, d), (None, "embed"))}
+
+    return {"blocks": [block() for _ in range(cfg.num_mem_blocks)],
+            "uses": [use() for _ in range(n_uses)]}
+
+
+def shared_block_uses(cfg: ModelConfig) -> list[int]:
+    """The layer before which each use of a shared block runs, in order:
+    the published ``hybrid_layer_ids`` below ``n_layers``; else (the repo's
+    block) one after every ``shared_attn_period`` Mamba layers, the last of
+    which may follow the final layer."""
+    if cfg.hybrid_layer_ids:
+        return [i for i in cfg.hybrid_layer_ids if i < cfg.n_layers]
     period = max(cfg.shared_attn_period, 1)
-    n_super = cfg.n_layers // period
-    trailing = cfg.n_layers - n_super * period
-    return n_super, period, trailing
+    return [period * (j + 1) for j in range(cfg.n_layers // period)]
 
 
 def model_decls(cfg: ModelConfig) -> dict:
@@ -106,10 +133,12 @@ def model_decls(cfg: ModelConfig) -> dict:
         d["layers"] = {"ln": L.norm_decls(cfg, layers=cfg.n_layers),
                        "mamba": L.mamba_decls(cfg, layers=cfg.n_layers)}
     elif cfg.family == "hybrid":
-        n_super, per, trailing = zamba_structure(cfg)
+        n_uses = len(shared_block_uses(cfg))
         d["layers"] = {"ln": L.norm_decls(cfg, layers=cfg.n_layers),
                        "mamba": L.mamba_decls(cfg, layers=cfg.n_layers)}
-        d["shared"] = _shared_attn_decls(cfg, n_super)
+        d["shared"] = (_published_shared_decls(cfg, n_uses)
+                       if cfg.num_mem_blocks
+                       else _shared_attn_decls(cfg, n_uses))
     else:
         raise ValueError(cfg.family)
 
@@ -313,14 +342,7 @@ def forward(ctx: L.Ctx, cfg: ModelConfig, params, batch: dict, *,
     elif cfg.family == "ssm":
         def body(h, xs):
             p, st = xs
-            x_in = L.apply_norm(cfg, p["ln"], h)
-            ssm = st["ssm"] if st is not None else None
-            conv = st["conv"] if st is not None else None
-            out, (new_ssm, new_conv) = L.apply_mamba(
-                ctx, cfg, p["mamba"], x_in, ssm_state=ssm, conv_state=conv)
-            new_st = ({"ssm": new_ssm, "conv": new_conv}
-                      if st is not None else None)
-            return h + out, new_st
+            return _mamba_layer(ctx, cfg, p, h, st)
 
         body = _remat(ctx, body)
         st = cache["mamba"] if cache is not None else None
@@ -329,10 +351,10 @@ def forward(ctx: L.Ctx, cfg: ModelConfig, params, batch: dict, *,
             new_cache = {"mamba": new_st}
 
     elif cfg.family == "hybrid":
-        h, aux, new_cache = _zamba_forward(ctx, cfg, params, h, cos, sin,
-                                           cache=cache,
-                                           cache_index=cache_index,
-                                           block_tables=block_tables)
+        h, aux, new_cache = _hybrid_forward(ctx, cfg, params, h, cos, sin,
+                                            cache=cache,
+                                            cache_index=cache_index,
+                                            block_tables=block_tables)
     else:
         raise ValueError(cfg.family)
 
@@ -346,165 +368,124 @@ def forward(ctx: L.Ctx, cfg: ModelConfig, params, batch: dict, *,
 # zamba2 hybrid
 # ---------------------------------------------------------------------------
 
-def _mamba_segment(ctx, cfg, stack, h, st):
-    def body(h, xs):
-        p, s = xs
-        x_in = L.apply_norm(cfg, p["ln"], h)
-        ssm = s["ssm"] if s is not None else None
-        conv = s["conv"] if s is not None else None
-        out, (new_ssm, new_conv) = L.apply_mamba(
-            ctx, cfg, p["mamba"], x_in, ssm_state=ssm, conv_state=conv)
-        new_s = {"ssm": new_ssm, "conv": new_conv} if s is not None else None
-        return h + out, new_s
-
-    body = _remat(ctx, body)
-    return _stack_scan(ctx, body, h, (stack, st))
+def _mamba_layer(ctx, cfg, p, h, s, t=None):
+    """h + mamba(norm(h + t)) (t: a published shared block's output, or
+    None); s: the layer's {"ssm", "conv"} state or None."""
+    x_in = L.apply_norm(cfg, p["ln"], h if t is None else h + t)
+    out, (new_ssm, new_conv) = L.apply_mamba(
+        ctx, cfg, p["mamba"], x_in,
+        ssm_state=s["ssm"] if s is not None else None,
+        conv_state=s["conv"] if s is not None else None)
+    new_s = {"ssm": new_ssm, "conv": new_conv} if s is not None else None
+    return h + out, new_s
 
 
-def _shared_block(ctx, cfg, p, inv_idx, h, emb0, cos, sin, *,
-                  cache=None, cache_index=None, block_tables=None):
-    """Zamba2 shared attention block on concat(h, emb0), with per-invocation
-    LoRA on q."""
+def _mamba_range(ctx, cfg, stack, h, st, start, stop, t=None):
+    """Mamba layers [start, stop) of the whole layer ``stack``; the first
+    one's input takes ``t``.  Without state the range's weights are
+    scanned.  With state (``st``: the whole stack's) each layer's weights
+    and state are read at its index and the state written back in place,
+    so a serving step copies no range of weights or of state."""
+    if start >= stop:
+        return h, st
+    if st is None:
+        def body(carry, p):
+            h, t = carry
+            h, _ = _mamba_layer(ctx, cfg, p, h, None, t)
+            return (h, None if t is None else jnp.zeros_like(t)), None
+
+        seg = jax.tree.map(lambda a: a[start:stop], stack)
+        (h, _), _ = _stack_scan(ctx, _remat(ctx, body), (h, t), seg)
+        return h, None
+
+    def body(carry, i):
+        h, t, st = carry
+        with jax.named_scope("ssm"):       # the state's traffic is the layer's
+            s = jax.tree.map(lambda a: a[i], st)
+        h, s = _mamba_layer(ctx, cfg, jax.tree.map(lambda a: a[i], stack),
+                            h, s, t)
+        with jax.named_scope("ssm"):
+            st = jax.tree.map(
+                lambda a, n: jax.lax.dynamic_update_index_in_dim(
+                    a, n.astype(a.dtype), i, axis=0), st, s)
+        return (h, None if t is None else jnp.zeros_like(t), st), None
+
+    (h, _, st), _ = _stack_scan(ctx, _remat(ctx, body), (h, t, st),
+                                jnp.arange(start, stop, dtype=jnp.int32))
+    return h, st
+
+
+def _use_attention(ctx, cfg, p, x, cos, sin, j, kv, cache_index,
+                   block_tables, q_delta=None):
+    """Shared attention of use j, whose rows are layer j of the
+    ``kv_shared`` stack: a paged pool stack is passed whole and updated in
+    place at that layer; a dense cache is sliced and put back."""
+    if kv is None or block_tables is not None:
+        return L.apply_attention(ctx, cfg, p, x, cos, sin, cache=kv,
+                                 cache_index=cache_index,
+                                 block_tables=block_tables,
+                                 layer=None if kv is None else j,
+                                 q_delta=q_delta)
+    out, kv_j = L.apply_attention(ctx, cfg, p, x, cos, sin,
+                                  cache=jax.tree.map(lambda a: a[j], kv),
+                                  cache_index=cache_index, q_delta=q_delta)
+    return out, jax.tree.map(lambda a, n: a.at[j].set(n), kv, kv_j)
+
+
+def _repo_block(ctx, cfg, p, j, h, emb0, cos, sin, kv, cache_index,
+                block_tables):
+    """The repo's zamba2 shared block, use j: attention with a per-use
+    LoRA on q, then a SwiGLU MLP, both over norm(concat(h, emb0)), each
+    added to the residual.  Returns (h, None, kv)."""
     c = ctx.cdtype
-    xcat = jnp.concatenate([h, emb0], axis=-1)
-    a_in = L.apply_norm(cfg, p["ln1"], xcat)
-    # LoRA delta on q for this invocation
+    a_in = L.apply_norm(cfg, p["ln1"], jnp.concatenate([h, emb0], axis=-1))
     with jax.named_scope("attn_proj"):
-        la = p["lora_a"][inv_idx].astype(c)
-        lb = p["lora_b"][inv_idx].astype(c)
         B, S = a_in.shape[:2]
-        q_delta = (a_in @ la @ lb).reshape(B, S, cfg.n_heads, cfg.head_dim)
-
-    # attention with q = Wq x + LoRA(x)
-    attn_p = dict(p["attn"])
-    out, new_cache = _attention_with_qdelta(
-        ctx, cfg, attn_p, a_in, q_delta, cos, sin, cache=cache,
-        cache_index=cache_index, block_tables=block_tables)
+        q_delta = (a_in @ p["lora_a"][j].astype(c) @ p["lora_b"][j].astype(c)
+                   ).reshape(B, S, cfg.n_heads, cfg.head_dim)
+    out, kv = _use_attention(ctx, cfg, p["attn"], a_in, cos, sin, j, kv,
+                             cache_index, block_tables, q_delta)
     h = h + out
     m_in = L.apply_norm(cfg, p["ln2"], jnp.concatenate([h, emb0], axis=-1))
+    return h + L.apply_mlp(ctx, cfg, p["mlp"], m_in), None, kv
+
+
+def _published_block(ctx, cfg, p, j, h, emb0, cos, sin, kv, cache_index,
+                     block_tables):
+    """The published Zamba2 shared block, use j: block j mod
+    ``num_mem_blocks`` attends over norm(concat(h, emb0)); its MLP runs on
+    the normed attention output with use j's adapters; use j's projection
+    of the result is t.  No residual of its own: t enters the input of the
+    Mamba layer that follows.  Returns (h, t, kv)."""
+    blk, use = p["blocks"][j % cfg.num_mem_blocks], p["uses"][j]
+    x = L.apply_norm(cfg, blk["ln1"], jnp.concatenate([h, emb0], axis=-1))
+    o, kv = _use_attention(ctx, cfg, blk["attn"], x, cos, sin, j, kv,
+                           cache_index, block_tables)
+    m = L.apply_mlp(ctx, cfg, blk["mlp"], L.apply_norm(cfg, blk["ln2"], o),
+                    adapter=(use["adapter_a"], use["adapter_b"]))
     with jax.named_scope("mlp"):
-        gate = jnp.einsum("bsd,df->bsf", m_in, p["mlp"]["w_gate"].astype(c))
-        up = jnp.einsum("bsd,df->bsf", m_in, p["mlp"]["w_up"].astype(c))
-        up = ctx.cst(up, "act_batch", "act_seq", "act_mlp")
-        mlp_out = jnp.einsum("bsf,fd->bsd", jax.nn.silu(gate) * up,
-                             p["mlp"]["w_down"].astype(c))
-    return h + mlp_out, new_cache
+        t = jnp.einsum("bsd,de->bse", m, use["out"].astype(ctx.cdtype))
+    return h, t, kv
 
 
-def _attention_with_qdelta(ctx, cfg, p, x, q_delta, cos, sin, *,
-                           cache=None, cache_index=None, block_tables=None):
-    c = ctx.cdtype
-    B, S = x.shape[:2]
-    H, K, hd = cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    with jax.named_scope("attn_proj"):
-        q = jnp.einsum("bsd,de->bse", x, p["wq"].astype(c)
-                       ).reshape(B, S, H, hd) + q_delta
-        k = jnp.einsum("bsd,de->bse", x, p["wk"].astype(c)
-                       ).reshape(B, S, K, hd)
-        v = jnp.einsum("bsd,de->bse", x, p["wv"].astype(c)
-                       ).reshape(B, S, K, hd)
-        q = ctx.cst(q, "act_batch", "act_seq", "act_heads", None)
-        if cfg.use_rope:
-            q = L.apply_rope(q, cos, sin, cfg.rotary_pct)
-            k = L.apply_rope(k, cos, sin, cfg.rotary_pct)
-    out, new_cache = _shared_attend(ctx, cfg, q, k, v, cache, cache_index,
-                                    block_tables)
-    return L.attn_out_proj(ctx, p["wo"], out), new_cache
-
-
-@L.scoped("attn_core")
-def _shared_attend(ctx, cfg, q, k, v, cache, cache_index, block_tables):
-    """The shared block's cache write and attention kernel; returns (out
-    (B, S, H, hd), the new cache or None)."""
-    c = ctx.cdtype
-    B, S = q.shape[:2]
-    scale = cfg.head_dim ** -0.5
-    from repro.kernels import ops
-    if cache is not None and block_tables is not None:
-        # paged kv_shared pool: same table as the rows keys of the other
-        # families (uniform per-slot kv_len), same no-cst rationale as the
-        # paged branch of L.apply_attention
-        per_slot = jnp.ndim(cache_index) >= 1
-        idx_vec = (jnp.asarray(cache_index, jnp.int32) if per_slot
-                   else jnp.full((B,), cache_index, jnp.int32))
-        ck, cv = ops.kv_cache_update_paged(cache["k"], cache["v"], k, v,
-                                           idx_vec, block_tables,
-                                           mode=ctx.run.kernel_mode)
-        kv_len = idx_vec + S
-        out = ops.decode_attention_paged(q, ck.astype(c), cv.astype(c),
-                                         kv_len, block_tables, scale=scale,
-                                         mode=ctx.run.kernel_mode)
-        return out, {"k": ck, "v": cv}
-    new_cache = None
-    if cache is not None:
-        per_slot = jnp.ndim(cache_index) >= 1
-        if not per_slot and L._use_seqsharded_decode(ctx, cfg, q, cache):
-            return L._decode_attention_seqsharded(
-                ctx, cfg, q, cache, k, v, cache_index, scale=scale)
-        if per_slot:
-            ck, cv = ops.kv_cache_update(
-                cache["k"], cache["v"], k, v,
-                jnp.asarray(cache_index, jnp.int32),
-                mode=ctx.run.kernel_mode)
-        else:
-            ck = jax.lax.dynamic_update_slice(
-                cache["k"], k.astype(cache["k"].dtype), (0, cache_index, 0, 0))
-            cv = jax.lax.dynamic_update_slice(
-                cache["v"], v.astype(cache["v"].dtype), (0, cache_index, 0, 0))
-        ck = ctx.cst(ck, "act_batch", "act_kv_seq", None, None)
-        cv = ctx.cst(cv, "act_batch", "act_kv_seq", None, None)
-        new_cache = {"k": ck, "v": cv}
-        kv_len = jnp.broadcast_to(
-            jnp.asarray(cache_index + S, jnp.int32), (B,))
-        out = ops.decode_attention(q, ck.astype(c), cv.astype(c), kv_len,
-                                   scale=scale, mode=ctx.run.kernel_mode,
-                                   block_kv=ctx.run.attn_block_kv)
-    else:
-        out = ops.attention(q, k, v, causal=cfg.causal, scale=scale,
-                            mode=ctx.run.kernel_mode,
-                            block_q=ctx.run.attn_block_q,
-                            block_kv=ctx.run.attn_block_kv,
-                            naive_below=ctx.run.naive_attn_below)
-    return out, new_cache
-
-
-def _zamba_forward(ctx, cfg, params, h, cos, sin, *, cache=None,
-                   cache_index=None, block_tables=None):
-    n_super, per, trailing = zamba_structure(cfg)
+def _hybrid_forward(ctx, cfg, params, h, cos, sin, *, cache=None,
+                    cache_index=None, block_tables=None):
+    """The Mamba stack with a shared block before the layers of
+    ``shared_block_uses`` (use j's KV rows: layer j of ``kv_shared``)."""
     emb0 = h
-    aux = jnp.zeros((), jnp.float32)
-    slice_stack = lambda tree, s, e: jax.tree.map(lambda a: a[s:e], tree)
-    st_all = cache["mamba"] if cache is not None else None
-    kv_shared = cache["kv_shared"] if cache is not None else None
-    new_st, new_kv = [], []
-    for i in range(n_super):
-        seg = slice_stack(params["layers"], i * per, (i + 1) * per)
-        st = slice_stack(st_all, i * per, (i + 1) * per) if st_all is not None else None
-        h, ns = _mamba_segment(ctx, cfg, seg, h, st)
-        if ns is not None:
-            new_st.append(ns)
-        kv_i = (jax.tree.map(lambda a: a[i], kv_shared)
-                if kv_shared is not None else None)
-        h, nkv = _shared_block(ctx, cfg, params["shared"], i, h, emb0,
-                               cos, sin, cache=kv_i, cache_index=cache_index,
-                               block_tables=block_tables)
-        if nkv is not None:
-            new_kv.append(nkv)
-    if trailing:
-        seg = slice_stack(params["layers"], n_super * per, cfg.n_layers)
-        st = (slice_stack(st_all, n_super * per, cfg.n_layers)
-              if st_all is not None else None)
-        h, ns = _mamba_segment(ctx, cfg, seg, h, st)
-        if ns is not None:
-            new_st.append(ns)
-    new_cache = None
-    if cache is not None:
-        cat = lambda *ts: jnp.concatenate(ts, axis=0)
-        new_cache = {
-            "mamba": jax.tree.map(cat, *new_st) if len(new_st) > 1 else new_st[0],
-            "kv_shared": jax.tree.map(lambda *ts: jnp.stack(ts, 0), *new_kv),
-        }
-    return h, aux, new_cache
+    stack = params["layers"]
+    st = cache["mamba"] if cache is not None else None
+    kv = cache["kv_shared"] if cache is not None else None
+    block = _published_block if cfg.num_mem_blocks else _repo_block
+    uses = shared_block_uses(cfg)
+    stops = uses + [cfg.n_layers]
+    h, st = _mamba_range(ctx, cfg, stack, h, st, 0, stops[0])
+    for j, start in enumerate(uses):
+        h, t, kv = block(ctx, cfg, params["shared"], j, h, emb0, cos, sin,
+                         kv, cache_index, block_tables)
+        h, st = _mamba_range(ctx, cfg, stack, h, st, start, stops[j + 1], t)
+    new_cache = None if cache is None else {"mamba": st, "kv_shared": kv}
+    return h, jnp.zeros((), jnp.float32), new_cache
 
 
 # ===========================================================================
@@ -545,9 +526,9 @@ def init_cache(ctx: L.Ctx, cfg: ModelConfig, batch: int, max_seq: int,
     if cfg.family == "ssm":
         return {"mamba": ms(cfg, batch, c, layers=cfg.n_layers)}
     if cfg.family == "hybrid":
-        n_super, _, _ = zamba_structure(cfg)
         return {"mamba": ms(cfg, batch, c, layers=cfg.n_layers),
-                "kv_shared": kv(cfg, batch, max_seq, c, layers=n_super)}
+                "kv_shared": kv(cfg, batch, max_seq, c,
+                                layers=len(shared_block_uses(cfg)))}
     raise ValueError(f"{cfg.family} has no decode cache (encoder-only)")
 
 
@@ -589,10 +570,9 @@ def init_paged_cache(ctx: L.Ctx, cfg: ModelConfig, batch: int, max_seq: int,
         return {"kv": pkv(cfg, n_blocks, block_size, c, layers=cfg.n_layers),
                 "block_tables": table}
     if cfg.family == "hybrid":
-        n_super, _, _ = zamba_structure(cfg)
         return {"mamba": ms(cfg, batch, c, layers=cfg.n_layers),
                 "kv_shared": pkv(cfg, n_blocks, block_size, c,
-                                 layers=n_super),
+                                 layers=len(shared_block_uses(cfg))),
                 "block_tables": table}
     raise ValueError(f"{cfg.family} has no decode cache (encoder-only)")
 
@@ -614,7 +594,8 @@ def cache_logical_axes(cfg: ModelConfig):
 # ===========================================================================
 #
 # Every decode-cache leaf is laid out (stack, B, ...): axis 0 is the layer
-# stack (n_layers, or n_super for the zamba2 shared-attention cache) and
+# stack (n_layers, or the shared blocks' uses for the zamba2 shared-
+# attention cache) and
 # axis 1 is the batch SLOT.  ``cache_slot_spec`` names, per top-level cache
 # key, what one slot's lane means; ``export_slot``/``import_slot`` lift a
 # lane out of one engine's cache and install it into another's — including
@@ -849,7 +830,10 @@ def export_slot_paged(cfg: ModelConfig, cache, slot: int, blocks,
                 lambda a: ops.slot_gather(a, slot, axis=1, mode=mode),
                 cache[key])
         else:
-            payload[key] = jax.tree.map(lambda a: a[:, blk, off], cache[key])
+            # pool rows carry zero columns past head_dim
+            # (layers.paged_head_dim): the payload holds head_dim
+            payload[key] = jax.tree.map(
+                lambda a: a[:, blk, off, ..., :cfg.head_dim], cache[key])
     return quantize_payload(payload) if quantize else payload
 
 
@@ -887,11 +871,12 @@ def import_slot_paged(cfg: ModelConfig, cache, payload, slot: int, blocks,
 
         def scatter_rows(full, lane):
             if (lane.shape[0] != full.shape[0]
-                    or lane.shape[2:] != full.shape[3:]):
+                    or lane.shape[2:-1] != full.shape[3:-1]
+                    or lane.shape[-1] != cfg.head_dim):
                 raise ValueError(f"{key}: payload lane {lane.shape} does "
                                  f"not fit pool {full.shape}")
-            return full.at[:, blk, off].set(
-                jnp.asarray(lane).astype(full.dtype))
+            return full.at[:, blk, off].set(ops.pad_head(
+                jnp.asarray(lane), full.shape[-1]).astype(full.dtype))
 
         new_cache[key] = jax.tree.map(scatter_rows, cache[key], payload[key])
     return new_cache

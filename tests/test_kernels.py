@@ -439,6 +439,89 @@ def test_ssd_decode_step_matches_naive_tail():
     np.testing.assert_allclose(y_t, y_all[:, -1], atol=1e-4, rtol=1e-4)
 
 
+def test_ssd_kernel_two_groups_with_initial_state():
+    """Two B/C groups (zamba2-7b's ssm_ngroups) continuing from a carried
+    state, as a prefill chunk after an earlier one: head h reads group
+    h // (H / G)."""
+    B, S, H, P, G, N = 1, 64, 4, 16, 2, 16
+    ks = jax.random.split(jax.random.PRNGKey(4), 6)
+    x = jax.random.normal(ks[0], (B, S, H, P))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
+    A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.5)
+    Bm = jax.random.normal(ks[3], (B, S, G, N))
+    Cm = jax.random.normal(ks[4], (B, S, G, N))
+    h0 = jax.random.normal(ks[5], (B, H, P, N))
+    D = jnp.linspace(0.5, 1.5, H)
+    y, st = ssd(x, dt, A, Bm, Cm, D, h0=h0, chunk=16, interpret=True)
+    y_ref, st_ref = ref.ssd_naive(x, dt, A, Bm, Cm, D, h0=h0)
+    np.testing.assert_allclose(y, y_ref, atol=1e-3, rtol=1e-3)
+    np.testing.assert_allclose(st, st_ref, atol=1e-3, rtol=1e-3)
+    # the groups are read: the same call with them swapped differs
+    y_sw, _ = ssd(x, dt, A, Bm[:, :, ::-1], Cm[:, :, ::-1], D, h0=h0,
+                  chunk=16, interpret=True)
+    assert float(jnp.max(jnp.abs(y_sw - y_ref))) > 0.1
+
+
+def test_ssd_decode_step_groups_match_naive_tail():
+    """The one-token recurrence with two B/C groups continues the scan."""
+    B, S, H, P, G, N = 2, 17, 4, 8, 2, 8
+    ks = jax.random.split(jax.random.PRNGKey(6), 5)
+    x = jax.random.normal(ks[0], (B, S, H, P))
+    dt = jax.nn.softplus(jax.random.normal(ks[1], (B, S, H)))
+    A = -jnp.exp(jax.random.normal(ks[2], (H,)) * 0.5)
+    Bm = jax.random.normal(ks[3], (B, S, G, N))
+    Cm = jax.random.normal(ks[4], (B, S, G, N))
+    D = jnp.ones((H,))
+    y_all, st_all = ref.ssd_naive(x, dt, A, Bm, Cm, D)
+    _, st = ref.ssd_chunked(x[:, :-1], dt[:, :-1], A, Bm[:, :-1],
+                            Cm[:, :-1], chunk=16)
+    y_t, st_t = ref.ssd_decode_step(st, x[:, -1], dt[:, -1], A, Bm[:, -1],
+                                    Cm[:, -1], D)
+    np.testing.assert_allclose(y_t, y_all[:, -1], atol=1e-4, rtol=1e-4)
+    np.testing.assert_allclose(st_t, st_all, atol=1e-4, rtol=1e-4)
+
+
+@pytest.mark.parametrize("Sq,K", [(1, 32), (8, 32), (64, 8)])
+def test_paged_ops_at_head_224(Sq, K):
+    """zamba2-7b's shared attention: multi-head (g = 1) at head size 224,
+    not a multiple of 128 lanes, so its pool rows are 256 wide
+    (``layers.paged_head_dim``).  Through the kernels: the cache write into
+    one layer of a stack, then attention over the slot's pages, against
+    the oracles on the unpadded rows; 32 kv heads where interpret mode
+    allows it, 8 at a 64-row chunk."""
+    B, max_blocks, bs, D, Dp = 2, 8, 16, 224, 256
+    k_pool, v_pool, tables = _paged_pools(B * max_blocks + 1, bs, K, D, B,
+                                          max_blocks)
+    pad = ((0, 0),) * 4 + ((0, Dp - D),)
+    k_stack = jnp.pad(jnp.stack([k_pool, -v_pool]), pad).astype(jnp.bfloat16)
+    v_stack = jnp.pad(jnp.stack([v_pool, k_pool]), pad).astype(jnp.bfloat16)
+    ks = jax.random.split(KEY, 3)
+    kn = jax.random.normal(ks[0], (B, Sq, K, D)).astype(jnp.bfloat16)
+    vn = jax.random.normal(ks[1], (B, Sq, K, D)).astype(jnp.bfloat16)
+    q = jax.random.normal(ks[2], (B, Sq, K, D)).astype(jnp.bfloat16)
+    idx = jnp.array([3, 100 - Sq], jnp.int32)
+    layer = jnp.asarray(1, jnp.int32)
+    got_k, got_v = ops.kv_cache_update_paged(k_stack, v_stack, kn, vn, idx,
+                                             tables, layer,
+                                             mode="pallas_interpret")
+    exp_k, exp_v = ref.kv_cache_update_paged_ref(
+        k_stack[1, ..., :D], v_stack[1, ..., :D], kn, vn, idx, tables)
+    np.testing.assert_array_equal(got_k[1, ..., :D], exp_k)
+    np.testing.assert_array_equal(got_v[1, ..., :D], exp_v)
+    assert not jnp.any(got_k[..., D:]) and not jnp.any(got_v[..., D:])
+    np.testing.assert_array_equal(got_k[0], k_stack[0])
+    kv_len = idx + Sq
+    scale = (D / 2) ** -0.5
+    out = ops.decode_attention_paged(q, got_k, got_v, kv_len, tables, layer,
+                                     scale=scale, mode="pallas_interpret")
+    exp = ref.decode_attention_paged_ref(q, exp_k, exp_v, kv_len, tables,
+                                         scale=scale)
+    assert out.shape == exp.shape
+    np.testing.assert_allclose(out.astype(jnp.float32),
+                               exp.astype(jnp.float32), atol=2e-2,
+                               rtol=2e-2)
+
+
 GMM_SHAPES = [(4, 64, 32, 48, 32, 16, 16), (2, 100, 72, 130, 32, 32, 64),
               (8, 16, 128, 16, 16, 64, 16)]
 

@@ -105,9 +105,15 @@ def test_moe_aux_loss_nonzero():
 
 
 def test_zamba_structure_covers_layers():
+    """The repo's block runs after every 6 of the 38 layers; the published
+    Zamba2-7B's before the layers its config lists."""
     cfg = get_model_config("zamba2-1.2b")
-    n_super, per, trailing = lm.zamba_structure(cfg)
-    assert n_super * per + trailing == cfg.n_layers == 38
+    assert cfg.n_layers == 38
+    assert lm.shared_block_uses(cfg) == [6, 12, 18, 24, 30, 36]
+    big = get_model_config("zamba2-7b")
+    assert lm.shared_block_uses(big) == big.hybrid_layer_ids
+    assert lm.shared_block_uses(dataclasses.replace(big, n_layers=41)) == [
+        6, 11, 17, 23, 29, 35]
 
 
 def test_scan_vs_unrolled_equivalence():

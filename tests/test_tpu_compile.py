@@ -12,15 +12,20 @@ import pytest
 from jax.sharding import SingleDeviceSharding
 
 from repro.configs.registry import get_model_config
+from repro.kernels import ops
 from repro.kernels.flash_attention import (cache_update, cache_update_paged,
                                            flash_attention, flash_decode,
                                            flash_decode_paged)
 from repro.kernels.grouped_matmul import grouped_matmul
 from repro.kernels.ssd import ssd
+from repro.models.layers import paged_head_dim
 
 MINITRON = get_model_config("minitron-4b")   # 24 q heads, 8 kv, head_dim 128
 ZAMBA2 = get_model_config("zamba2-1.2b")     # SSD heads 64 x 64, state 64
 OLMOE = get_model_config("olmoe-1b-7b")      # 64 experts, d 2048, d_ff 1024
+# 32 heads of 224 (multi-head, not a multiple of 128 lanes); SSD 112 heads
+# of 64, 2 B/C groups, chunk 256
+ZAMBA2_7B = get_model_config("zamba2-7b")
 BATCH, BLOCK = 8, 16
 
 
@@ -112,6 +117,64 @@ def test_cache_update_paged_compiles(chip, sn):
         lambda kp, vp, kn, vn, i, t, ly: cache_update_paged(kp, vp, kn, vn,
                                                             i, t, ly),
         pool, pool, new, new, idx, tables, layer)
+
+
+def _zamba2_pool(chip, n_blocks=1536, uses=6):
+    c = ZAMBA2_7B
+    return _sds((uses, n_blocks, BLOCK, c.n_kv_heads, paged_head_dim(c)),
+                jnp.bfloat16, chip)
+
+
+@pytest.mark.parametrize("sq", [1, 8, 256])
+def test_flash_decode_paged_compiles_at_head_224(chip, sq):
+    """zamba2-7b's serving shapes through the dispatch the model calls: 24
+    slots (a decode step), or one slot (a prefill chunk), over the 6-use
+    pool stack, whose rows are 256 wide (the DMA of a page takes no 224);
+    at a 256-row chunk the stacked q operand is 32 x 256 rows."""
+    c = ZAMBA2_7B
+    b = 24 if sq == 1 else 1
+    max_blocks = 4096 // BLOCK
+    q = _sds((b, sq, c.n_heads, c.head_dim), jnp.bfloat16, chip)
+    pool = _zamba2_pool(chip)
+    lens = _sds((b,), jnp.int32, chip)
+    tables = _sds((b, max_blocks), jnp.int32, chip)
+    layer = _sds((), jnp.int32, chip)
+    _kernel_compiles(
+        lambda q, k, v, n, t, ly: ops.decode_attention_paged(
+            q, k, v, n, t, ly, scale=(c.head_dim / 2) ** -0.5,
+            mode="pallas"),
+        q, pool, pool, lens, tables, layer)
+
+
+@pytest.mark.parametrize("sn", [1, 256])
+def test_cache_update_paged_compiles_at_head_224(chip, sn):
+    c = ZAMBA2_7B
+    b = 24 if sn == 1 else 1
+    max_blocks = 4096 // BLOCK
+    pool = _zamba2_pool(chip)
+    new = _sds((b, sn, c.n_kv_heads, c.head_dim), jnp.bfloat16, chip)
+    idx = _sds((b,), jnp.int32, chip)
+    tables = _sds((b, max_blocks), jnp.int32, chip)
+    layer = _sds((), jnp.int32, chip)
+    _kernel_compiles(
+        lambda kp, vp, kn, vn, i, t, ly: ops.kv_cache_update_paged(
+            kp, vp, kn, vn, i, t, ly, mode="pallas"),
+        pool, pool, new, new, idx, tables, layer)
+
+
+def test_ssd_compiles_with_two_groups(chip):
+    """A 256-row prefill chunk of zamba2-7b: 112 heads over 2 groups,
+    chunk 256, continuing from a carried state."""
+    c = ZAMBA2_7B
+    S, H = c.ssm_chunk, c.ssm_heads
+    x = _sds((1, S, H, c.ssm_headdim), jnp.bfloat16, chip)
+    dt = _sds((1, S, H), jnp.bfloat16, chip)
+    a = _sds((H,), jnp.float32, chip)
+    bc = _sds((1, S, c.ssm_ngroups, c.ssm_state), jnp.bfloat16, chip)
+    h0 = _sds((1, H, c.ssm_headdim, c.ssm_state), jnp.float32, chip)
+    _kernel_compiles(lambda x, dt, a, b, cc, h: ssd(x, dt, a, b, cc, h0=h,
+                                                    chunk=c.ssm_chunk),
+                     x, dt, a, bc, bc, h0)
 
 
 def test_ssd_compiles(chip):
